@@ -49,6 +49,9 @@ import argparse
 
 
 def main() -> None:
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=[
         "retrieval", "completion", "abstract", "kernels", "serving",

@@ -19,7 +19,7 @@ Every strategy exists in two backends sharing one output contract:
                 Exact, simple, and fine while N is small.
 * **compact** — per-hop work is O(C): seeds are expanded into a fixed-capacity
                 sorted *workset* of C candidate ids (:mod:`repro.core.workset`,
-                backed by the ``kernels.frontier_expand`` mark kernel), and the
+                with the ``kernels.frontier_expand`` hop), and the
                 strategy runs over the workset-local induced adjacency.  When
                 no query overflows the capacity, the output — nodes, mask,
                 dist, including tie order — is bitwise identical to the dense
@@ -97,8 +97,8 @@ def bfs_distances(
 
     def hop(carry, h):
         dist, frontier = carry
-        # one pull hop through the kernels.bfs_frontier op: Pallas-tiled on
-        # TPU, the pure-jnp gather elsewhere (size-gated inside the op)
+        # one pull hop through the kernels.bfs_frontier op (its jnp arm:
+        # the Pallas kernel is opt-in, see that op's docstring)
         reach = bfs_frontier_ops.frontier_hop(frontier, nbr, nbr_mask)
         new = reach & (dist == INF)
         dist = jnp.where(new, h + 1, dist)

@@ -84,7 +84,7 @@ def build_workset(
     *,
     max_hops: int,
     cap: int,
-    use_kernel: bool | None = None,
+    use_kernel: bool = False,
 ) -> Workset:
     """Expand seeds into the capacity-``cap`` workset of the max_hops ball."""
     n = nbr.shape[0]

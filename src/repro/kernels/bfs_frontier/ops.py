@@ -1,4 +1,10 @@
-"""Public wrapper: bool<->int8 plumbing, padding, interpret fallback."""
+"""Public wrapper: bool<->int8 plumbing, padding, interpret fallback.
+
+The jnp ref is the default on every backend.  The Pallas kernel is opt-in
+(``use_kernel=True``, interpret mode off TPU, for parity tests): the TPU
+compiler refuses it — its ``(1, N+1)`` frontier block breaks the (8, 128)
+block rule and its in-VMEM 1-D gather is not supported.
+"""
 from __future__ import annotations
 
 import functools
@@ -21,14 +27,9 @@ def frontier_hop(
     nbr_mask: jnp.ndarray,
     *,
     blk_n: int = 512,
-    use_kernel: bool | None = None,
+    use_kernel: bool = False,
 ):
     q, n = frontier.shape
-    if use_kernel is None:
-        # the Pallas path only pays off where it compiles natively; off-TPU
-        # the interpret-mode fallback is orders of magnitude slower than the
-        # jnp ref, so default dispatch is TPU-and-large-enough
-        use_kernel = _on_tpu() and n >= blk_n
     if not use_kernel:
         return ref.frontier_hop(frontier, nbr, nbr_mask)
     blk = min(blk_n, n)

@@ -1,7 +1,10 @@
 """Public workset hop-expansion ops: membership mark dispatch + one hop.
 
-``ws_member`` picks the Pallas mark kernel on TPU (interpret mode when
-forced elsewhere, for parity tests) and the searchsorted ref otherwise.
+``ws_member`` runs the searchsorted ref by default on every backend.  The
+Pallas mark kernel is opt-in (``use_kernel=True``, interpret mode off TPU,
+for parity tests): the TPU compiler refuses it — its ``(1, C)`` blocks
+break the (8, 128) block rule and its in-VMEM 1-D gathers are not
+supported — so it cannot be the default until it is rewritten.
 
 ``expand_hop`` is the full fixed-shape hop: a ``(Q, C, K)`` neighbor
 gather over the workset followed by a sort/unique dedup-merge.  All heavy
@@ -55,11 +58,9 @@ def ws_member(
     cand: jnp.ndarray,  # (Q, W) int32
     *,
     blk_w: int = 1024,
-    use_kernel: bool | None = None,
+    use_kernel: bool = False,
 ) -> jnp.ndarray:
     """(Q, W) bool membership of each candidate in its row's sorted workset."""
-    if use_kernel is None:
-        use_kernel = _on_tpu()
     if not use_kernel:
         return ref.ws_member(ws_ids, cand)
     w = cand.shape[1]
@@ -88,7 +89,7 @@ def expand_hop(
     hop_dist,  # scalar int32 in [1, band-2]: distance of nodes added now
     *,
     band: int,  # max_hops + 2: exclusive upper bound on packed distances
-    use_kernel: bool | None = None,
+    use_kernel: bool = False,
 ):
     """One workset expansion hop (see module docstring for the algorithm).
 
@@ -116,8 +117,6 @@ def expand_hop(
     cand = jnp.where(valid[:, :, None] & nbr_mask[safe], nbr[safe], n)
     cand = cand.reshape(q, c * k)
 
-    if use_kernel is None:
-        use_kernel = _on_tpu()
     if use_kernel:
         # mark members with the Pallas kernel; only fresh ids enter the sort
         present = ws_member(ws_ids, cand, use_kernel=True)

@@ -34,15 +34,21 @@ def _topk_sim_kernel(q_ref, e_ref, s_ref, i_ref, *, k: int, c_blk: int, n_valid:
     )  # (Q_BLK, C_BLK)
     col = j * c_blk + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(col < n_valid, scores, -jnp.inf)
-    # iterative top-k within the tile (k is small: <= 128)
+    # iterative top-k within the tile (k is small: <= 128); the k winners
+    # collect in registers and leave in one (Q_BLK, k) store per output
+    lane = jax.lax.broadcasted_iota(jnp.int32, (scores.shape[0], k), 1)
+    s_acc = jnp.full((scores.shape[0], k), -jnp.inf, jnp.float32)
+    i_acc = jnp.zeros((scores.shape[0], k), jnp.int32)
     for t in range(k):
         m = jnp.max(scores, axis=1)  # (Q_BLK,)
         a = jnp.argmax(scores, axis=1).astype(jnp.int32)  # (Q_BLK,)
-        s_ref[:, 0, t] = m
-        i_ref[:, 0, t] = a + j * c_blk
+        s_acc = jnp.where(lane == t, m[:, None], s_acc)
+        i_acc = jnp.where(lane == t, (a + j * c_blk)[:, None], i_acc)
         # mask the winner out for the next round
         hit = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) == a[:, None]
         scores = jnp.where(hit, -jnp.inf, scores)
+    s_ref[0] = s_acc
+    i_ref[0] = i_acc
 
 
 @functools.partial(
@@ -60,8 +66,10 @@ def topk_sim_blocks(
 ):
     """q: (Q, D) fp32, emb: (N, D) fp32; Q % q_blk == 0, N % c_blk == 0.
 
-    Returns (scores (Q, n_c_blocks, k), indices (Q, n_c_blocks, k)) of the
-    per-tile top-k; caller merges.
+    Returns (scores (n_c_blocks, Q, k), indices (n_c_blocks, Q, k)) of the
+    per-tile top-k; caller merges.  The tile axis leads so that each output
+    block ``(1, q_blk, k)`` ends in ``(q_blk, k)`` — a multiple of 8 rows
+    and the array's full last dim, which the TPU lowering requires.
     """
     Q, D = q.shape
     N, _ = emb.shape
@@ -74,8 +82,8 @@ def topk_sim_blocks(
         _topk_sim_kernel, k=k, c_blk=c_blk, n_valid=n_valid
     )
     out_shape = (
-        jax.ShapeDtypeStruct((Q, N // c_blk, k), jnp.float32),
-        jax.ShapeDtypeStruct((Q, N // c_blk, k), jnp.int32),
+        jax.ShapeDtypeStruct((N // c_blk, Q, k), jnp.float32),
+        jax.ShapeDtypeStruct((N // c_blk, Q, k), jnp.int32),
     )
     return pl.pallas_call(
         kern,
@@ -85,8 +93,8 @@ def topk_sim_blocks(
             pl.BlockSpec((c_blk, D), lambda i, j: (j, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((q_blk, 1, k), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((q_blk, 1, k), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, q_blk, k), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((1, q_blk, k), lambda i, j: (j, i, 0)),
         ),
         out_shape=out_shape,
         interpret=interpret,

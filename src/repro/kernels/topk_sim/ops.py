@@ -51,8 +51,9 @@ def topk_similarity(
         qp, ep, k=kk, q_blk=q_blk, c_blk=c_blk, n_valid=N,
         interpret=not _on_tpu(),
     )
-    s_flat = s_blk.reshape(Qp, -1)
-    i_flat = i_blk.reshape(Qp, -1)
+    # (n_blocks, Qp, kk) -> (Qp, n_blocks * kk), tile-major per query
+    s_flat = s_blk.transpose(1, 0, 2).reshape(Qp, -1)
+    i_flat = i_blk.transpose(1, 0, 2).reshape(Qp, -1)
     top_s, pos = jax.lax.top_k(s_flat, k)
     top_i = jnp.take_along_axis(i_flat, pos, axis=1)
     return top_s[:Q], top_i[:Q]

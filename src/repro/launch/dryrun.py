@@ -274,15 +274,6 @@ def _analysis_cfg(spec, shape, n_layers):
     return cfg
 
 
-def _cost_dict(compiled) -> dict:
-    """Normalize Compiled.cost_analysis() across jax versions: newer releases
-    return a list of per-computation dicts, older ones a single dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def _compile_cell(spec, shape, mesh, cfg, *, edge_chunk=16384, n_micro=None):
     builder = {"lm": build_lm, "gnn": build_gnn, "recsys": build_recsys}[spec.family]
     kw = {}
@@ -335,7 +326,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool = False,
             + ma.output_size_in_bytes - ma.alias_size_in_bytes
         ),
     }
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     rec["cost_full_program"] = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -355,7 +346,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool = False,
                 edge_chunk=padded_edges(shape) if spec.family == "gnn" else 16384,
                 n_micro=1,
             )
-            ca_l = _cost_dict(comp_l)
+            ca_l = comp_l.cost_analysis() or {}
             pts[L] = {
                 "flops": float(ca_l.get("flops", 0.0)),
                 "bytes": float(ca_l.get("bytes accessed", 0.0)),

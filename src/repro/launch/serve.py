@@ -1,6 +1,6 @@
-"""Serving launcher: --arch <id> spins up the serving engine with the arch's
-reduced config on CPU (full configs serve via the dry-run sharding on real
-hardware).
+"""Serving launcher: --arch <id> spins up the serving engine on the arch's
+reduced smoke config, or with ``--published`` on its published config
+(full widths, published vocabulary, random weights from a seed).
 
 Two modes:
 
@@ -23,11 +23,17 @@ survivors.
         --index sharded --shards 4
     PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b --rag \
         --replicas 3 --crash-replica 3
+    PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b --rag \
+        --published --nodes 169343 --retrieval compact   # one TPU v5e chip
+
+The process exits non-zero when any request failed, was served degraded
+(retrieval-free) or stale, unless ``--fault-rate`` asked for injected faults.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 import jax
@@ -35,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as C
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.transformer import model as tm
 from repro.serving import (
     FaultyReplica, FaultyRetrieval, RAGRequest, RAGServeEngine, ReplicaRouter,
@@ -63,8 +70,73 @@ def _print_decode_stats(ds: dict) -> None:
               f"exhaustion before reaching max_new_tokens")
 
 
-def _serve_tokens(cfg, args) -> None:
-    params = tm.init_params(jax.random.PRNGKey(0), cfg)
+def lm_config(arch: str, *, published: bool, vocab_size=None):
+    """The arch's published config (``published=True``) or its reduced
+    smoke config.  ``vocab_size`` is the tokenizer's id range: the published
+    vocabulary is kept and must cover it, the smoke config takes it as its
+    vocabulary (its own 128 ids cover no graph tokenizer)."""
+    spec = C.get_config(arch)
+    if not published:
+        cfg = spec.reduced_cfg
+        return cfg if vocab_size is None else \
+            dataclasses.replace(cfg, vocab=vocab_size)
+    cfg = spec.model_cfg
+    if vocab_size is not None and vocab_size > cfg.vocab:
+        raise ValueError(
+            f"tokenizer ids reach {vocab_size - 1}, past {arch}'s published "
+            f"vocabulary of {cfg.vocab}"
+        )
+    return cfg
+
+
+def build_corpus(nodes: int, *, avg_deg: int = 8, seed: int = 0):
+    """Seeded citation graph (OGBN-Arxiv stand-in, 128-d features):
+    returns (CSR graph, ELL adjacency, node embeddings on the device)."""
+    from repro.graph import csr_to_ell, generators
+
+    g = generators.citation_graph(nodes, avg_deg=avg_deg, seed=seed)
+    return g, csr_to_ell(g), jnp.asarray(g.node_feat)
+
+
+def graph_tokenizer(g, *, max_len: int = 96, node_budget: int = 8):
+    """Word tokenizer over the corpus vocabulary + graph linearizer."""
+    from repro.core import GraphTokenizer, Vocab
+
+    return GraphTokenizer(Vocab.build(g.node_text), max_len=max_len,
+                          node_budget=node_budget)
+
+
+def build_rag_pipeline(g, ell, emb, pcfg, tok):
+    """The ``RGLPipeline`` that ``RAGServeEngine`` serves, with the stage-1
+    index named by ``pcfg``."""
+    from repro.core import RGLPipeline, index_from_config
+
+    return RGLPipeline(
+        graph=ell, index=index_from_config(emb, pcfg), node_emb=emb,
+        tokenizer=tok, node_text=g.node_text, config=pcfg,
+    )
+
+
+def build_lm(arch: str, *, published: bool, vocab_size=None, seed: int = 0):
+    """(config, random weights from ``seed``) for the serving LM."""
+    cfg = lm_config(arch, published=published, vocab_size=vocab_size)
+    return cfg, tm.init_params(jax.random.PRNGKey(seed), cfg)
+
+
+def arena_len(cfg, tok, max_new: int) -> int:
+    """KV arena length: the linearized graph prompt (<= tokenizer max_len)
+    plus generated tokens must fit; sliding_window only bounds attention
+    reach, and a windowed arch keeps its full window."""
+    return max(cfg.sliding_window or 0, tok.max_len + max_new + 1)
+
+
+def unserved(done: list) -> list:
+    """Requests that did not get a retrieval-backed answer."""
+    return [r for r in done if r.failed or r.degraded or r.stale]
+
+
+def _serve_tokens(args) -> None:
+    cfg, params = build_lm(args.arch, published=args.published)
     cache_len = cfg.sliding_window or 128
     eng = ServeEngine(params, cfg, slots=args.slots, cache_len=cache_len,
                       spec_decode=args.spec_decode,
@@ -88,24 +160,16 @@ def _serve_tokens(cfg, args) -> None:
     _print_decode_stats(eng.decode_stats())
 
 
-def _serve_rag(cfg, args) -> None:
-    from repro.core import (
-        GraphTokenizer, PipelineConfig, RGLPipeline, Vocab, index_from_config,
-    )
-    from repro.graph import csr_to_ell, generators
+def _serve_rag(args) -> list:
+    from repro.core import PipelineConfig
 
-    g = generators.citation_graph(args.nodes, avg_deg=8, seed=0)
-    ell = csr_to_ell(g)
-    emb = jnp.asarray(g.node_feat)
-    vocab = Vocab.build(g.node_text)
-    # the arch LM decodes the graph tokenizer's vocabulary
-    cfg = dataclasses.replace(cfg, vocab=vocab.size)
-    tok = GraphTokenizer(vocab, max_len=96, node_budget=8)
+    g, ell, emb = build_corpus(args.nodes)
     pcfg = PipelineConfig(strategy="bfs", k_seeds=3, max_nodes=16,
                           filter_budget=6, index_kind=args.index,
                           index_shards=args.shards,
                           retrieval_mode=args.retrieval,
                           workset_cap=args.workset_cap)
+    tok = graph_tokenizer(g)
     store = None
     if args.mutate_rate > 0:
         from repro.core import MutableGraphStore
@@ -117,20 +181,15 @@ def _serve_rag(cfg, args) -> None:
         store = MutableGraphStore.build(g, index_kind=args.index)
         pipe = store.make_pipeline(tokenizer=tok, config=pcfg)
     else:
-        index = index_from_config(emb, pcfg)
-        pipe = RGLPipeline(
-            graph=ell, index=index, node_emb=emb, tokenizer=tok,
-            node_text=g.node_text, config=pcfg,
-        )
+        pipe = build_rag_pipeline(g, ell, emb, pcfg, tok)
+    cfg, params = build_lm(args.arch, published=args.published,
+                           vocab_size=tok.vocab.size)
     if args.fault_rate > 0:
         # fault-injection demo mode: a seeded fraction of retrieval rows
         # raise / stall / corrupt, exercising the retry + degradation path
         pipe = FaultyRetrieval(pipe, seed=args.fault_seed,
                                fault_rate=args.fault_rate)
-    params = tm.init_params(jax.random.PRNGKey(0), cfg)
-    # the linearized graph prompt (<= tokenizer max_len) plus generated
-    # tokens must fit the arena; sliding_window only bounds attention reach
-    cache_len = max(cfg.sliding_window or 0, 96 + args.max_new + 1)
+    cache_len = arena_len(cfg, tok, args.max_new)
     # one ServingConfig carries every CLI knob (CLI flag > RGL_* env >
     # default — the same precedence rule as the engine's kwargs)
     serve_cfg = ServingConfig.resolve(
@@ -205,6 +264,7 @@ def _serve_rag(cfg, args) -> None:
               f"{s['mutation_invalidated']} cache entries invalidated, "
               f"{s['stale_rejects']} stale puts rejected)")
     _print_decode_stats(s)
+    return done
 
 
 def _drain_with_mutations(eng, store, args, max_steps: int = 10_000) -> list:
@@ -245,7 +305,7 @@ def _drain_with_mutations(eng, store, args, max_steps: int = 10_000) -> list:
     return done
 
 
-def _serve_rag_fleet(pipe, g, emb, params, cfg, serve_cfg, args) -> None:
+def _serve_rag_fleet(pipe, g, emb, params, cfg, serve_cfg, args) -> list:
     # shed/deadline knobs move to the router's front door: the router pins
     # the absolute deadline at submit and sheds on queue overflow, so the
     # per-replica engines run unbounded underneath it
@@ -303,9 +363,11 @@ def _serve_rag_fleet(pipe, g, emb, params, cfg, serve_cfg, args) -> None:
             line += (f"; retries={h['retries']}, timeouts={h['timeouts']}, "
                      f"failed={h['failed']}, degraded={h['degraded']}")
         print(line)
+    return done
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     lm_archs = [a for a in C.ARCH_IDS if C.get_config(a).family == "lm"]
     ap.add_argument("--arch", required=True, choices=lm_archs)
@@ -314,6 +376,10 @@ def main():
     ap.add_argument("--max_new", type=int, default=12)
     ap.add_argument("--rag", action="store_true",
                     help="serve end-to-end through the fused RAG engine")
+    ap.add_argument("--published", action="store_true",
+                    help="serve the arch's published config (full widths "
+                         "and vocabulary) instead of its reduced smoke "
+                         "config")
     ap.add_argument("--nodes", type=int, default=1000,
                     help="synthetic graph size for --rag")
     ap.add_argument("--index", default="brute",
@@ -452,11 +518,18 @@ def main():
                     help="seed for the per-row fault schedule")
     args = ap.parse_args()
 
-    cfg = C.get_config(args.arch).reduced_cfg
-    if args.rag:
-        _serve_rag(cfg, args)
-    else:
-        _serve_tokens(cfg, args)
+    if not args.rag:
+        _serve_tokens(args)
+        return
+    bad = unserved(_serve_rag(args))
+    if bad and args.fault_rate == 0:
+        for r in bad:
+            kind = "failed" if r.failed else \
+                "degraded" if r.degraded else "stale"
+            print(f"  request {r.uid}: {kind}"
+                  + (f" ({r.error})" if r.error else ""), file=sys.stderr)
+        sys.exit(f"{len(bad)} request(s) served without fresh retrieval "
+                 f"and no --fault-rate was asked for")
 
 
 if __name__ == "__main__":

@@ -32,7 +32,11 @@ def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 # init
 # --------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def init_params(key, cfg: TransformerConfig) -> dict:
+    """Random weights from ``key``.  Jitted so that each float32 draw fuses
+    with its cast: at published widths the eager draw of ``w2`` alone would
+    be a multi-GB float32 transient on top of the weights already built."""
     dtype = jnp.dtype(cfg.dtype)
     d, h, kv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     keys = jax.random.split(key, 8)

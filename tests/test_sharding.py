@@ -1,5 +1,6 @@
-"""ShardedIndex: exact parity with the unsharded brute scan, the
-hierarchical merge oracle, and a forced multi-device CPU mesh subprocess."""
+"""ShardedIndex: parity with the unsharded brute scan (same ids, scores
+within the stated tolerance), the hierarchical merge oracle, and a forced
+multi-device CPU mesh subprocess."""
 import os
 import subprocess
 import sys
@@ -11,13 +12,23 @@ import pytest
 
 from repro.core import BruteIndex, ShardedIndex, hierarchical_topk_merge
 from repro.core.indexing import build_index
+from repro.core.sharding import SCORE_ATOL, SCORE_RTOL
 
 
-def _bitwise_equal(a, b) -> bool:
-    a, b = np.asarray(a), np.asarray(b)
-    return a.shape == b.shape and np.array_equal(
-        a.view(np.uint32), b.view(np.uint32)
-    )
+def _assert_same_topk(brute, sharded) -> None:
+    """The sharded contract: scores within (SCORE_RTOL, SCORE_ATOL) of the
+    unsharded scan's, and the same id at every rank whose score is not
+    within that tolerance of a neighbouring rank's (near-ties may swap)."""
+    bs, bi = (np.asarray(a) for a in brute)
+    ss, si = (np.asarray(a) for a in sharded)
+    assert bs.shape == ss.shape and bi.shape == si.shape
+    np.testing.assert_allclose(ss, bs, rtol=SCORE_RTOL, atol=SCORE_ATOL)
+    tol = SCORE_ATOL + SCORE_RTOL * np.abs(bs)
+    gap = np.abs(np.diff(bs, axis=1))
+    tied = np.zeros(bs.shape, bool)
+    tied[:, 1:] |= gap <= tol[:, 1:]
+    tied[:, :-1] |= gap <= tol[:, :-1]
+    np.testing.assert_array_equal(bi[~tied], si[~tied])
 
 
 # ---------------------------------------------------------------- merge ----
@@ -59,32 +70,36 @@ def test_hierarchical_merge_breaks_ties_by_id(rng):
 def test_sharded_matches_brute_bitwise(rng, n, n_shards, k):
     emb = rng.standard_normal((n, 32)).astype(np.float32)
     q = rng.standard_normal((5, 32)).astype(np.float32)
-    bs, bi = BruteIndex.build(emb).search(q, k)
-    ss, si = ShardedIndex.build(emb, n_shards=n_shards).search(q, k)
-    assert _bitwise_equal(bs, ss)
-    np.testing.assert_array_equal(np.asarray(bi), np.asarray(si))
+    brute = BruteIndex.build(emb).search(q, k)
+    _assert_same_topk(
+        brute, ShardedIndex.build(emb, n_shards=n_shards).search(q, k))
 
 
 def test_sharded_tie_breaking_with_duplicate_rows(rng):
-    # duplicated rows across shard boundaries -> exact score ties; the
-    # merge must reproduce lax.top_k's lowest-global-id-first order
+    # duplicated rows across shard boundaries -> score ties; every tied
+    # group must hold the same ids, and untied ranks the same id
     base = rng.standard_normal((40, 16)).astype(np.float32)
     emb = np.concatenate([base, base, base])  # ids i, i+40, i+80 tie
     q = base[:4] + 0.0
-    bs, bi = BruteIndex.build(emb).search(q, 9)
-    ss, si = ShardedIndex.build(emb, n_shards=5).search(q, 9)
-    assert _bitwise_equal(bs, ss)
-    np.testing.assert_array_equal(np.asarray(bi), np.asarray(si))
+    brute = BruteIndex.build(emb).search(q, 9)
+    sharded = ShardedIndex.build(emb, n_shards=5).search(q, 9)
+    _assert_same_topk(brute, sharded)
+    # the 9 hits are 3 tie groups of 3 duplicates: same id sets per group
+    bi, si = np.asarray(brute[1]), np.asarray(sharded[1])
+    for g in range(3):
+        cols = slice(3 * g, 3 * g + 3)
+        np.testing.assert_array_equal(np.sort(bi[:, cols]),
+                                      np.sort(si[:, cols]))
+        np.testing.assert_array_equal(np.sort(si[:, cols]) % 40,
+                                      si[:, cols] % 40)
 
 
 def test_sharded_single_shard_and_build_index_kinds(rng):
     emb = rng.standard_normal((50, 8)).astype(np.float32)
     q = rng.standard_normal((3, 8)).astype(np.float32)
-    bs, bi = BruteIndex.build(emb).search(q, 4)
+    brute = BruteIndex.build(emb).search(q, 4)
     one = build_index(emb, kind="sharded", n_shards=1)
-    ss, si = one.search(q, 4)
-    assert _bitwise_equal(bs, ss)
-    np.testing.assert_array_equal(np.asarray(bi), np.asarray(si))
+    _assert_same_topk(brute, one.search(q, 4))
     sivf = build_index(emb, kind="sharded_ivf", n_shards=2, n_clusters=4,
                        nprobe=4)
     s2, i2 = sivf.search(q, 4)
@@ -96,10 +111,8 @@ def test_sharded_empty_trailing_shard(rng):
     rows_per_shard=2 and shard 3 is all padding); both inners must cope."""
     emb = rng.standard_normal((5, 8)).astype(np.float32)
     q = rng.standard_normal((2, 8)).astype(np.float32)
-    bs, bi = BruteIndex.build(emb).search(q, 5)
-    ss, si = ShardedIndex.build(emb, n_shards=4).search(q, 5)
-    assert _bitwise_equal(bs, ss)
-    np.testing.assert_array_equal(np.asarray(bi), np.asarray(si))
+    brute = BruteIndex.build(emb).search(q, 5)
+    _assert_same_topk(brute, ShardedIndex.build(emb, n_shards=4).search(q, 5))
     sv = ShardedIndex.build(emb, n_shards=4, inner="ivf", n_clusters=4,
                             nprobe=4)
     s2, i2 = sv.search(q, 3)
@@ -126,6 +139,7 @@ def test_sharded_ivf_recall_vs_brute(rng):
 _PARITY_SCRIPT = """
 import numpy as np, jax
 from repro.core import BruteIndex, ShardedIndex
+from repro.core.sharding import SCORE_ATOL, SCORE_RTOL
 
 assert jax.device_count() == 4, jax.device_count()
 rng = np.random.default_rng(7)
@@ -135,17 +149,22 @@ for n, s, k in [(2898, 4, 9), (3001, 4, 17), (4096, 8, 5)]:
     bs, bi = BruteIndex.build(emb).search(q, k)
     idx = ShardedIndex.build(emb, n_shards=s)
     assert idx.mesh.size == 4, idx.mesh.size  # a real 4-way mesh
+    # every device holds only its own shards between searches
+    held = {sh.device: sh.data.shape[0] for sh in
+            idx.emb_shards.addressable_shards}
+    assert len(held) == 4 and set(held.values()) == {s // 4}, held
     ss, si = idx.search(q, k)
-    assert np.array_equal(np.asarray(bs).view(np.uint32),
-                          np.asarray(ss).view(np.uint32)), (n, s, "scores")
+    np.testing.assert_allclose(np.asarray(ss), np.asarray(bs),
+                               rtol=SCORE_RTOL, atol=SCORE_ATOL)
     assert np.array_equal(np.asarray(bi), np.asarray(si)), (n, s, "ids")
 print("MESH_PARITY_OK")
 """
 
 
 def test_sharded_parity_on_forced_multidevice_mesh():
-    """Bit-identical (scores, ids) on a real >= 4-way CPU mesh, including
-    N not divisible by the shard count.  Runs in a subprocess because
+    """Same ids and scores within tolerance on a real >= 4-way CPU mesh,
+    including N not divisible by the shard count, with each device holding
+    only its own shards.  Runs in a subprocess because
     --xla_force_host_platform_device_count must be set before jax init."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
