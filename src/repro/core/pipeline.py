@@ -12,6 +12,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import filters, graph_retrieval, node_retrieval, tokenization
 from repro.core.graph_retrieval import Subgraph
 from repro.graph.ell import ELLGraph
@@ -140,10 +141,14 @@ class RGLPipeline:
         )
 
     def retrieve(self, query_emb, encoder=None) -> RetrievalResult:
-        """Stages 2+3+filter — the sub-pipeline completion tasks use."""
-        _, seeds = self.retrieve_seeds(query_emb, encoder=encoder)
-        sub = self.retrieve_subgraph(seeds)
-        sub = self.filter(sub, query_emb, seeds)
+        """Stages 2+3+filter — the sub-pipeline completion tasks use.  Each
+        stage's host dispatch runs in a span of its own."""
+        with tracing.span("retrieval.index"):
+            _, seeds = self.retrieve_seeds(query_emb, encoder=encoder)
+        with tracing.span("retrieval.subgraph"):
+            sub = self.retrieve_subgraph(seeds)
+        with tracing.span("retrieval.filter"):
+            sub = self.filter(sub, query_emb, seeds)
         q = jnp.asarray(query_emb)
         n_valid = 1 if q.ndim == 1 else int(q.shape[0])
         return RetrievalResult(sub=sub, seeds=seeds, n_valid=n_valid,

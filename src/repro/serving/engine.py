@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.models.transformer import model as tm
 from repro.models.transformer.config import TransformerConfig
 from repro.serving.drafter import draft_tokens
@@ -105,6 +106,8 @@ class Request:
     # prompt mismatch falls back to the ordinary prefill path.
     shared_prefix: object = None
     pin_to: object = None
+    # the engine's clock (``now_fn``) when the first token reached the host
+    first_token_at: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -321,8 +324,10 @@ class ServeEngine:
         spec_decode: Optional[bool] = None, draft_window: Optional[int] = None,
         paged_kv: Optional[bool] = None, block_size: Optional[int] = None,
         pool_blocks: Optional[int] = None, prefix_share: Optional[bool] = None,
+        now_fn=time.monotonic,
     ):
         self.params = params
+        self._now = now_fn
         self.cfg = cfg
         self.slots = slots
         self.cache_len = cache_len
@@ -791,49 +796,9 @@ class ServeEngine:
                 max(len(reqs[j].prompt_ids) for j, _ in fresh_pairs),
                 self.cache_len,
             )
-            toks = np.zeros((self.slots, bucket), np.int32)
-            tl = np.zeros((self.slots,), np.int32)
-            for f, (j, _) in enumerate(fresh_pairs):
-                L = len(reqs[j].prompt_ids)  # submit() guarantees L < Sc
-                toks[f, :L] = np.asarray(reqs[j].prompt_ids, np.int32)
-                tl[f] = L
-            logits, fresh = _prefill_batch(
-                self.params, jnp.asarray(toks), jnp.asarray(tl),
-                self.cfg, self.cache_len,
-            )
-            self.prefill_batches += 1
-            self.prefill_rows += len(fresh_pairs)
-            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (slots,)
-            rows = np.zeros(self.slots, np.int32)
-            newly = np.zeros(self.slots, bool)
-            tl_slot = np.zeros(self.slots, np.int32)
-            for f, (j, i) in enumerate(fresh_pairs):
-                rows[i] = f
-                newly[i] = True
-                tl_slot[i] = tl[f]
-            if self.paged_kv:
-                self._guard_alloc(
-                    sum(self._blocks_for(int(t)) for t in tl_slot),
-                    "admission prefill merge",
-                )
-                self.cache, self.cur_tok = _paged_merge_admitted(
-                    self.cache, fresh, self.cur_tok, first,
-                    jnp.asarray(rows), jnp.asarray(newly),
-                    jnp.asarray(tl_slot), self.block_size,
-                )
-                # replay the merge's pops: slot-index ascending, exactly the
-                # device allocator's order
-                for f, (j, i) in enumerate(fresh_pairs):
-                    self._pop_host(i, self._blocks_for(int(tl[f])))
-                self._live_dirty = True
-            else:
-                self.cache, self.cur_tok = _merge_admitted(
-                    self.cache, fresh, self.cur_tok, first,
-                    jnp.asarray(rows), jnp.asarray(newly),
-                )
-            first_np = np.asarray(first)
-            for f, (j, i) in enumerate(fresh_pairs):
-                first_by_slot[i] = int(first_np[f])
+            with tracing.span("prefill", rows=len(fresh_pairs),
+                              bucket=bucket):
+                self._prefill(reqs, fresh_pairs, bucket, first_by_slot)
         # -- shared population: alias donor blocks, no prefill dispatch
         if plans:
             mask = np.zeros(self.slots, bool)
@@ -880,10 +845,12 @@ class ServeEngine:
             )
         finished = []
         dead_at_admission = []
+        now = self._now()
         for j, i in enumerate(slot_ids):
             req = reqs[j]
             tok0 = int(first_by_slot[i])
             req.out_tokens.append(tok0)
+            req.first_token_at = now
             self.emitted_tokens += 1
             L = len(req.prompt_ids)
             self._cursor[i] = L  # merge/adopt pinned this slot's cursor
@@ -920,6 +887,56 @@ class ServeEngine:
             self._out_len_dev = jnp.asarray(self._out_len)
         return finished
 
+    def _prefill(self, reqs: list, fresh_pairs: list, bucket: int,
+                 first_by_slot: np.ndarray) -> None:
+        """One masked batched prefill of ``fresh_pairs`` (request index,
+        slot) padded to ``bucket``, merged into the arena; each slot's first
+        token lands in ``first_by_slot``."""
+        toks = np.zeros((self.slots, bucket), np.int32)
+        tl = np.zeros((self.slots,), np.int32)
+        for f, (j, _) in enumerate(fresh_pairs):
+            L = len(reqs[j].prompt_ids)  # submit() guarantees L < Sc
+            toks[f, :L] = np.asarray(reqs[j].prompt_ids, np.int32)
+            tl[f] = L
+        logits, fresh = _prefill_batch(
+            self.params, jnp.asarray(toks), jnp.asarray(tl),
+            self.cfg, self.cache_len,
+        )
+        self.prefill_batches += 1
+        self.prefill_rows += len(fresh_pairs)
+        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (slots,)
+        rows = np.zeros(self.slots, np.int32)
+        newly = np.zeros(self.slots, bool)
+        tl_slot = np.zeros(self.slots, np.int32)
+        for f, (j, i) in enumerate(fresh_pairs):
+            rows[i] = f
+            newly[i] = True
+            tl_slot[i] = tl[f]
+        if self.paged_kv:
+            self._guard_alloc(
+                sum(self._blocks_for(int(t)) for t in tl_slot),
+                "admission prefill merge",
+            )
+            self.cache, self.cur_tok = _paged_merge_admitted(
+                self.cache, fresh, self.cur_tok, first,
+                jnp.asarray(rows), jnp.asarray(newly),
+                jnp.asarray(tl_slot), self.block_size,
+            )
+            # replay the merge's pops: slot-index ascending, exactly the
+            # device allocator's order
+            for f, (j, i) in enumerate(fresh_pairs):
+                self._pop_host(i, self._blocks_for(int(tl[f])))
+            self._live_dirty = True
+        else:
+            self.cache, self.cur_tok = _merge_admitted(
+                self.cache, fresh, self.cur_tok, first,
+                jnp.asarray(rows), jnp.asarray(newly),
+            )
+        with tracing.span("prefill.wait"):
+            first_np = np.asarray(first)
+        for f, (j, i) in enumerate(fresh_pairs):
+            first_by_slot[i] = int(first_np[f])
+
     def _hist_append(self, i: int, toks: list) -> None:
         hl = int(self.hist_len[i])
         n = min(len(toks), self._hist_cap - hl)
@@ -951,10 +968,11 @@ class ServeEngine:
             finished.extend(self._retire_pool_exhausted())
         if not self.live.any():
             return finished
-        if self.spec_decode:
-            finished.extend(self._step_spec())
-        else:
-            finished.extend(self._step_one())
+        with tracing.span("decode", live=int(np.count_nonzero(self.live))):
+            if self.spec_decode:
+                finished.extend(self._step_spec())
+            else:
+                finished.extend(self._step_one())
         return finished
 
     def _step_one(self) -> list:
@@ -974,7 +992,8 @@ class ServeEngine:
         self._cursor += 1  # decode_step advances every slot's cursor
         finished = []
         live_before = self.live.copy()
-        toks = np.asarray(nxt)
+        with tracing.span("decode.wait"):
+            toks = np.asarray(nxt)
         for i, req in enumerate(self.active):
             if req is None or not self.live[i]:
                 continue
@@ -1019,7 +1038,8 @@ class ServeEngine:
         self.decode_steps += 1
         finished = []
         live_before = self.live.copy()
-        packed_np = np.asarray(packed)  # the step's single host sync
+        with tracing.span("decode.wait"):
+            packed_np = np.asarray(packed)  # the step's single host sync
         g_np, acc_np = packed_np[:, :w], packed_np[:, w]
         self._cursor += acc_np  # verify_step advanced every slot by accepted
         self._out_len += acc_np  # keep the host mirror bitwise in step

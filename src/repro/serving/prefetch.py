@@ -95,6 +95,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.serving.cache import CachedRetrieval, RetrievalCache
 
 
@@ -223,8 +224,13 @@ class AdmissionPrefetcher:
         dedupe into one ``retrieve_many`` row per quantized key (every
         duplicate still counts its own miss, as in sync admission), and
         keys already in flight defer to the owning wave with no counter
-        touched until that wave collects.
+        touched until that wave collects.  Every request is stamped
+        ``launched_at`` when the dispatch returns.
         """
+        with tracing.span("retrieval.launch"):
+            return self._launch(reqs, step, tokens)
+
+    def _launch(self, reqs: list, step: int, tokens: int) -> PrefetchWave:
         cache = self.cache
         t0 = self._now()
         wave = PrefetchWave(
@@ -288,6 +294,8 @@ class AdmissionPrefetcher:
                 self.batches += 1
                 self.queries += n_valid
         wave.launched_at = self._now()
+        for r in reqs:
+            r.launched_at = wave.launched_at
         self.launch_seconds += wave.launched_at - t0
         self._waves.append(wave)
         return wave
@@ -369,6 +377,20 @@ class AdmissionPrefetcher:
             self._sleep(min(1e-3, max(deadline - now, 1e-6)))
         return True
 
+    def _force(self, arrs, deadline: Optional[float]) -> tuple:
+        """Wait for ``arrs`` (until ``deadline``) and bring them to the
+        host: ``(host arrays, None)``, or ``(None, reason)`` on a timeout or
+        a raise."""
+        with tracing.span("retrieval.wait"):
+            if not self._wait_ready(arrs, deadline):
+                self.timeouts += 1
+                return None, \
+                    f"timeout: not ready in {self.retrieval_timeout_s}s"
+            try:
+                return tuple(np.asarray(a) for a in arrs), None
+            except Exception as exc:
+                return None, f"force: {exc}"
+
     def _validate_row(self, nodes, mask) -> Optional[str]:
         """Corrupt-result check: every node id under the valid mask must be
         a real node.  Returns an error reason, or None when clean."""
@@ -396,16 +418,13 @@ class AdmissionPrefetcher:
             return None, f"dispatch: {exc}"
         self.batches += 1
         self.queries += 1
-        arrs = (sub.nodes, sub.mask, sub.dist, seeds)
         deadline = None if self.retrieval_timeout_s is None else \
             t0 + self.retrieval_timeout_s
-        if not self._wait_ready(arrs, deadline):
-            self.timeouts += 1
-            return None, f"timeout: not ready in {self.retrieval_timeout_s}s"
-        try:
-            nodes, mask, dist, seeds_np = (np.asarray(a) for a in arrs)
-        except Exception as exc:
-            return None, f"force: {exc}"
+        forced, reason = self._force((sub.nodes, sub.mask, sub.dist, seeds),
+                                     deadline)
+        if forced is None:
+            return None, reason
+        nodes, mask, dist, seeds_np = forced
         err = self._validate_row(nodes[0], mask[0])
         if err is not None:
             return None, err
@@ -449,27 +468,21 @@ class AdmissionPrefetcher:
             arrs = (wave.sub.nodes, wave.sub.mask, wave.sub.dist, wave.seeds)
             deadline = None if self.retrieval_timeout_s is None else \
                 wave.launched_at + self.retrieval_timeout_s
-            if not self._wait_ready(arrs, deadline):
-                self.timeouts += 1
-                reason = f"timeout: not ready in {self.retrieval_timeout_s}s"
+            forced, reason = self._force(arrs, deadline)
+            if forced is None:
                 todo = {k: reason for k, _ in groups}
             else:
-                try:
-                    nodes, mask, dist, seeds_np = \
-                        (np.asarray(a) for a in arrs)
-                except Exception as exc:
-                    todo = {k: f"force: {exc}" for k, _ in groups}
-                else:
-                    for row, (k, idxs) in enumerate(groups):
-                        err = self._validate_row(nodes[row], mask[row])
-                        if err is not None:
-                            todo[k] = err
-                            continue
-                        entries[k] = CachedRetrieval(
-                            nodes=nodes[row].copy(), mask=mask[row].copy(),
-                            dist=dist[row].copy(), seeds=seeds_np[row].copy(),
-                            epoch=wave.epoch,
-                        )
+                nodes, mask, dist, seeds_np = forced
+                for row, (k, idxs) in enumerate(groups):
+                    err = self._validate_row(nodes[row], mask[row])
+                    if err is not None:
+                        todo[k] = err
+                        continue
+                    entries[k] = CachedRetrieval(
+                        nodes=nodes[row].copy(), mask=mask[row].copy(),
+                        dist=dist[row].copy(), seeds=seeds_np[row].copy(),
+                        epoch=wave.epoch,
+                    )
         for k, idxs in groups:
             if k not in todo:
                 continue

@@ -52,6 +52,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.pipeline import RGLPipeline
 from repro.models.transformer.config import TransformerConfig
 from repro.serving.cache import RetrievalCache
@@ -95,6 +96,13 @@ class RAGRequest:
     shed: bool = False
     error: Optional[str] = None  # reason for failed/shed
     deadline_at: Optional[float] = None  # absolute deadline, set at submit
+    # stamps on the engine's clock (``now_fn``), one read per stage: entered
+    # the pending queue, retrieval dispatched (or answered from the cache),
+    # prompt linearized, first token read back to the host
+    submitted_at: Optional[float] = None
+    launched_at: Optional[float] = None
+    prompt_at: Optional[float] = None
+    first_token_at: Optional[float] = None
 
 
 class RAGServeEngine:
@@ -220,7 +228,7 @@ class RAGServeEngine:
             draft_window=resolved.draft_window,
             paged_kv=resolved.paged_kv, block_size=resolved.kv_block_size,
             pool_blocks=resolved.kv_pool_blocks,
-            prefix_share=resolved.prefix_share,
+            prefix_share=resolved.prefix_share, now_fn=now_fn,
         )
         self.cache = retrieval_cache if retrieval_cache is not None else \
             RetrievalCache(capacity=resolved.cache_capacity,
@@ -368,6 +376,8 @@ class RAGServeEngine:
         still handed back by the next ``step()``.  Malformed requests raise
         ``ValueError`` and never enter the system."""
         self._validate(req)
+        if req.submitted_at is None:  # a failover re-dispatch keeps its own
+            req.submitted_at = self._now()
         if req.deadline_at is None:
             # a request arriving with deadline_at already pinned (a router
             # failover re-dispatch) keeps it: re-submitting must never
@@ -448,7 +458,9 @@ class RAGServeEngine:
                 else:
                     texts = []
                     r.retrieved_nodes = np.empty(0, np.int32)
-                ids, mask = tok.linearize(r.query_text, texts)
+                with tracing.span("linearize", uid=r.uid):
+                    ids, mask = tok.linearize(r.query_text, texts)
+                r.prompt_at = self._now()
                 r.prompt_ids = ids[mask]
                 inner = Request(
                     uid=r.uid, prompt_ids=r.prompt_ids,
@@ -485,19 +497,19 @@ class RAGServeEngine:
                 reqs = self._take_wave(1)
                 if not reqs:  # everything left was past deadline (shed)
                     continue
-                tok = self.engine.emitted_tokens
-                self.prefetcher.launch(reqs, step=self._step_no, tokens=tok)
-                self._tokenize_and_admit(self.prefetcher.collect(
-                    step=self._step_no, tokens=tok, sync=True))
+                self._admit_now(reqs)
             return
         reqs = self._take_wave()
-        if not reqs:
-            return
-        tok = self.engine.emitted_tokens
-        self.prefetcher.launch(reqs, step=self._step_no, tokens=tok)
-        self._tokenize_and_admit(
-            self.prefetcher.collect(step=self._step_no, tokens=tok, sync=True)
-        )
+        if reqs:
+            self._admit_now(reqs)
+
+    def _admit_now(self, reqs: list) -> None:
+        """Launch ``reqs``' retrieval, block on it and admit them."""
+        with tracing.span("admit"):
+            tok = self.engine.emitted_tokens
+            self.prefetcher.launch(reqs, step=self._step_no, tokens=tok)
+            self._tokenize_and_admit(self.prefetcher.collect(
+                step=self._step_no, tokens=tok, sync=True))
 
     def _launch_pending(self) -> None:
         while self.pending and self.prefetcher.can_launch():
@@ -521,19 +533,21 @@ class RAGServeEngine:
             # forfeit its whole overlap window, e.g. under trickle load
             # where wave size < free slots) — except via the idle-arena
             # fast path below, where there is nothing to overlap with
-            resolved = self.prefetcher.collect(
-                step=self._step_no, tokens=self.engine.emitted_tokens
-            )
-            self._launch_pending()
-            self._tokenize_and_admit(resolved)
+            with tracing.span("admit"):
+                resolved = self.prefetcher.collect(
+                    step=self._step_no, tokens=self.engine.emitted_tokens
+                )
+                self._launch_pending()
+                self._tokenize_and_admit(resolved)
         self._launch_pending()
         if (not self.engine.live.any() and not self.engine.queue
                 and self.prefetcher.in_flight):
             # idle arena: nothing to overlap with, don't stall a step
-            self._tokenize_and_admit(
-                self.prefetcher.collect(step=self._step_no,
-                                        tokens=self.engine.emitted_tokens)
-            )
+            with tracing.span("admit"):
+                self._tokenize_and_admit(
+                    self.prefetcher.collect(step=self._step_no,
+                                            tokens=self.engine.emitted_tokens)
+                )
 
     def _admit_continuous(self) -> None:
         """Continuous + prefetch: per-request launches, out-of-FIFO collect.
@@ -549,20 +563,22 @@ class RAGServeEngine:
             idx = self.prefetcher.ready_index()
             if idx is None:
                 break
-            resolved = self.prefetcher.collect_at(
-                idx, step=self._step_no, tokens=self.engine.emitted_tokens
-            )
-            self._launch_pending()
-            self._tokenize_and_admit(resolved)
+            with tracing.span("admit"):
+                resolved = self.prefetcher.collect_at(
+                    idx, step=self._step_no, tokens=self.engine.emitted_tokens
+                )
+                self._launch_pending()
+                self._tokenize_and_admit(resolved)
         if (not self.engine.live.any() and not self.engine.queue
                 and self.prefetcher.in_flight):
             # idle arena with nothing ready: block on the oldest wave rather
             # than burn empty steps (oldest first keeps deferred owners
             # resolving before their dependents)
-            self._tokenize_and_admit(
-                self.prefetcher.collect(step=self._step_no,
-                                        tokens=self.engine.emitted_tokens)
-            )
+            with tracing.span("admit"):
+                self._tokenize_and_admit(
+                    self.prefetcher.collect(step=self._step_no,
+                                            tokens=self.engine.emitted_tokens)
+                )
             self._launch_pending()
 
     # -- stepping -------------------------------------------------------------
@@ -570,19 +586,23 @@ class RAGServeEngine:
         """One engine step: admission (sync or prefetched, wave or
         continuous) + one decode step.  Returns the RAG requests that
         finished this step."""
-        if not self.prefetch:
-            self._admit_sync()
-        elif self.admission == "continuous":
-            self._admit_continuous()
-        else:
-            self._admit_prefetch()
-        finished_inner = self.engine.step()
+        with tracing.span("step", pending=len(self.pending),
+                          live=int(np.count_nonzero(self.engine.live)),
+                          inflight=self.prefetcher.in_flight):
+            if not self.prefetch:
+                self._admit_sync()
+            elif self.admission == "continuous":
+                self._admit_continuous()
+            else:
+                self._admit_prefetch()
+            finished_inner = self.engine.step()
         self._step_no += 1
         out = []
         for inner in finished_inner:
             r = self._inflight.pop(inner.ticket)
             r.out_tokens = inner.out_tokens
             r.truncated = inner.truncated
+            r.first_token_at = inner.first_token_at
             r.done = True
             out.append(r)
         if self._terminal:
@@ -629,6 +649,7 @@ class RAGServeEngine:
                 continue
             r.out_tokens = inner.out_tokens
             r.truncated = inner.truncated
+            r.first_token_at = inner.first_token_at
             self._fail(r, inner.error or reason)
         for ticket in list(self._inflight):
             # tickets whose inner request the decode engine lost track of

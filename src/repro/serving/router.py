@@ -283,9 +283,11 @@ class ReplicaRouter:
     @staticmethod
     def _reset_for_redispatch(req: RAGRequest) -> None:
         """Strip every per-attempt field so a survivor replica serves the
-        request from scratch.  ``deadline_at`` survives on purpose — a
-        failover must not extend the request's deadline budget."""
+        request from scratch.  ``deadline_at`` and ``submitted_at`` survive
+        on purpose — a failover must not extend the request's deadline
+        budget, nor hide the wait it caused."""
         req.out_tokens = []
+        req.launched_at = req.prompt_at = req.first_token_at = None
         req.prompt_ids = None
         req.retrieved_nodes = None
         req.cache_hit = False
