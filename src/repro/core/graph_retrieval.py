@@ -40,7 +40,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.workset import Workset, build_workset, localize, workset_adjacency
+from repro.core.workset import (
+    CSRGather, Workset, build_workset, csr_gather, localize, workset_adjacency,
+)
 from repro.graph.ell import ELLGraph
 from repro.kernels.bfs_frontier import ops as bfs_frontier_ops
 
@@ -233,11 +235,12 @@ def bfs_subgraph_compact(
     max_hops: int = 3,
     max_nodes: int = 64,
     workset_cap: int = 2048,
+    csr: Optional[CSRGather] = None,
 ) -> Subgraph:
     """RGL-BFS over the workset: O(C) per hop instead of O(N)."""
     n = nbr.shape[0]
     ws = build_workset(
-        nbr, nbr_mask, seeds, max_hops=max_hops, cap=workset_cap
+        nbr, nbr_mask, seeds, max_hops=max_hops, cap=workset_cap, csr=csr
     )
     key = ws.dist * jnp.int32(n) + jnp.where(ws.ids < n, ws.ids, 0)
     nodes, mask, topi = _select_ws(key, ws.ids < n, ws, max_nodes)
@@ -306,11 +309,12 @@ def dense_subgraph_compact(
     max_nodes: int = 64,
     n_rounds: int = 3,
     workset_cap: int = 2048,
+    csr: Optional[CSRGather] = None,
 ) -> Subgraph:
     """RGL-Dense over the workset: peeling scores C nodes per round, not N."""
     n, k = nbr.shape
     ws = build_workset(
-        nbr, nbr_mask, seeds, max_hops=max_hops, cap=workset_cap
+        nbr, nbr_mask, seeds, max_hops=max_hops, cap=workset_cap, csr=csr
     )
     wnbr, wmask = workset_adjacency(nbr, nbr_mask, ws.ids)
     valid = ws.ids < n
@@ -543,6 +547,7 @@ def steiner_subgraph_compact(
     max_hops: int = 4,
     max_nodes: int = 64,
     workset_cap: int = 2048,
+    csr: Optional[CSRGather] = None,
 ) -> Subgraph:
     """RGL-Steiner over the workset: the bridge scan walks C*K workset edges
     instead of N*K, Voronoi labels propagate over the local adjacency, and
@@ -550,7 +555,7 @@ def steiner_subgraph_compact(
     n, k = nbr.shape
     q, t = seeds.shape
     ws = build_workset(
-        nbr, nbr_mask, seeds, max_hops=max_hops, cap=workset_cap
+        nbr, nbr_mask, seeds, max_hops=max_hops, cap=workset_cap, csr=csr
     )
     c = ws.ids.shape[1]
     wnbr, wmask = workset_adjacency(nbr, nbr_mask, ws.ids)
@@ -673,6 +678,7 @@ def ppr_subgraph_compact(
     max_nodes: int = 64,
     max_hops: int = None,  # API parity; expansion radius is n_iter
     workset_cap: int = 2048,
+    csr: Optional[CSRGather] = None,
 ) -> Subgraph:
     """PPR over the workset.  After ``n_iter`` pull iterations mass reaches at
     most ``n_iter`` hops from the seeds, so the n_iter-hop workset carries the
@@ -681,7 +687,9 @@ def ppr_subgraph_compact(
     all positive-mass nodes coincide."""
     n, k = nbr.shape
     q = seeds.shape[0]
-    ws = build_workset(nbr, nbr_mask, seeds, max_hops=n_iter, cap=workset_cap)
+    ws = build_workset(
+        nbr, nbr_mask, seeds, max_hops=n_iter, cap=workset_cap, csr=csr
+    )
     c = ws.ids.shape[1]
     wnbr, wmask = workset_adjacency(nbr, nbr_mask, ws.ids)
     valid = ws.ids < n
@@ -727,6 +735,40 @@ COMPACT_STRATEGIES = {
 }
 
 
+def _compact_cap(g: ELLGraph, n_seeds: int, strategy: str, mode: str,
+                 workset_cap: int, kw: dict) -> int:
+    """The workset capacity where the compact backend runs, else 0."""
+    if mode not in ("dense", "compact", "auto"):
+        raise ValueError(f"unknown retrieval mode: {mode!r}")
+    if mode == "compact" or (
+        mode == "auto"
+        and strategy != "ppr"
+        and g.num_nodes >= AUTO_COMPACT_MIN_NODES
+        and workset_cap < g.num_nodes
+    ):
+        return max(workset_cap, kw.get("max_nodes", 64), n_seeds)
+    return 0
+
+
+def hop_gather(
+    g: ELLGraph,
+    n_seeds: int,
+    strategy: str = "bfs",
+    *,
+    mode: str = "auto",
+    workset_cap: int = 2048,
+    **kw,
+) -> tuple:
+    """The neighbor gather :func:`retrieve_subgraph` runs per hop, and its
+    proposal slots per query: ``("csr", E)`` or ``("ell", C*K)`` on the
+    compact backend, ``("dense", N*K)`` on the dense one."""
+    cap = _compact_cap(g, n_seeds, strategy, mode, workset_cap, kw)
+    if not cap:
+        return "dense", g.num_nodes * g.max_deg
+    csr = csr_gather(g, cap, n_seeds)
+    return ("ell", cap * g.max_deg) if csr is None else ("csr", csr.width)
+
+
 def retrieve_subgraph(
     g: ELLGraph,
     seeds: jnp.ndarray,
@@ -746,21 +788,16 @@ def retrieve_subgraph(
     — it stays dense under auto), with a transparent dense re-run when any
     query overflows.  The overflow check is host-side (one device sync);
     inside an outer ``jax.jit`` trace the flags are tracers, so the check
-    is skipped and the compact result is returned flags-and-all.
+    is skipped and the compact result is returned flags-and-all.  The
+    compact backend's hops gather from the graph's CSR view where that is
+    narrower than its ELL rows (:func:`repro.core.workset.csr_gather`).
     """
-    if mode not in ("dense", "compact", "auto"):
-        raise ValueError(f"unknown retrieval mode: {mode!r}")
     seeds = jnp.asarray(seeds, jnp.int32)
-    use_compact = mode == "compact" or (
-        mode == "auto"
-        and strategy != "ppr"
-        and g.num_nodes >= AUTO_COMPACT_MIN_NODES
-        and workset_cap < g.num_nodes
-    )
-    if use_compact:
-        cap = max(workset_cap, kw.get("max_nodes", 64), seeds.shape[1])
+    cap = _compact_cap(g, seeds.shape[1], strategy, mode, workset_cap, kw)
+    if cap:
         sub = COMPACT_STRATEGIES[strategy](
-            g.nbr, g.nbr_mask, seeds, workset_cap=cap, **kw
+            g.nbr, g.nbr_mask, seeds, workset_cap=cap,
+            csr=csr_gather(g, cap, seeds.shape[1]), **kw
         )
         if (
             mode == "auto"

@@ -142,10 +142,17 @@ class RGLPipeline:
 
     def retrieve(self, query_emb, encoder=None) -> RetrievalResult:
         """Stages 2+3+filter — the sub-pipeline completion tasks use.  Each
-        stage's host dispatch runs in a span of its own."""
+        stage's host dispatch runs in a span of its own; the subgraph's
+        names its per-hop gather and width (``graph_retrieval.hop_gather``).
+        """
         with tracing.span("retrieval.index"):
             _, seeds = self.retrieve_seeds(query_emb, encoder=encoder)
-        with tracing.span("retrieval.subgraph"):
+        c = self.config
+        gather, cand = graph_retrieval.hop_gather(
+            self.graph, seeds.shape[-1], c.strategy, mode=c.retrieval_mode,
+            workset_cap=c.workset_cap, max_nodes=c.max_nodes,
+        )
+        with tracing.span("retrieval.subgraph", gather=gather, cand=cand):
             sub = self.retrieve_subgraph(seeds)
         with tracing.span("retrieval.filter"):
             sub = self.filter(sub, query_emb, seeds)

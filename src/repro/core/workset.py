@@ -15,6 +15,19 @@ per-query flag is set and truncation is deterministic: entries are never
 evicted, so complete hops survive whole and the overflowing hop keeps its
 lowest fresh ids.
 
+Each hop proposes the neighbors of every member through one of two
+gathers (``kernels.frontier_expand.ops``), chosen from the graph's shape
+with no switch: the ELL gather's ``(Q, C, K)`` block, ``C*K`` slots per
+query, or — when the graph carries a CSR view (``csr_to_ell`` attaches
+one) and that is narrower — the CSR gather's ``E`` slots of real
+neighbors.  ``E`` is static, read from the degree sequence by
+:func:`csr_gather`: the sum of the largest degrees of as many rows as the
+hop can hold members, rounded up to a multiple of 128.  Hop 1 expands only
+the seeds, so its ``E`` is the sum over ``S`` rows; from the hop that can
+hold ``C`` members on, the sum of the ``C`` largest.  No hop's proposals
+exceed its ``E``, nothing is cut, and both gathers give bit-identical
+worksets.
+
 All retrieval strategies then run over the *workset-local induced
 adjacency* (``workset_adjacency``): ``(Q, C, K)`` neighbor slots holding
 positions into the workset, sentinel ``C`` where the neighbor is absent —
@@ -25,10 +38,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.graph.ell import ELLGraph
 from repro.kernels.frontier_expand import ops as fe_ops
 
 INF = jnp.int32(0x3FFFFFF)
@@ -51,6 +66,49 @@ class Workset:
 jax.tree_util.register_dataclass(
     Workset, data_fields=["ids", "dist", "overflow"], meta_fields=["num_nodes"]
 )
+
+
+@dataclasses.dataclass
+class CSRGather:
+    """The CSR hop gather: device CSR arrays and static per-hop widths
+    (hop h uses ``widths[min(h, len - 1)]``)."""
+
+    indptr: jnp.ndarray  # (N+1,) int32
+    indices: jnp.ndarray  # (nnz,) int32
+    widths: tuple  # proposal slots per query, hop 1 first; the last repeats
+
+    @property
+    def width(self) -> int:
+        """Widest hop: the slots per query once the workset can be full."""
+        return self.widths[-1]
+
+
+jax.tree_util.register_dataclass(
+    CSRGather, data_fields=["indptr", "indices"], meta_fields=["widths"]
+)
+
+
+def _ceil128(x: int) -> int:
+    return max(128, -(-x // 128) * 128)
+
+
+def csr_gather(g: ELLGraph, cap: int, n_seeds: int) -> Optional[CSRGather]:
+    """The CSR gather for worksets of ``cap`` grown from ``n_seeds`` seeds,
+    or None where the graph has no CSR view or ``E`` is no narrower than
+    the ELL gather's ``cap * K``."""
+    view = g.csr
+    if view is None or view.top_degree_sum(cap) == 0:
+        return None
+    if _ceil128(view.top_degree_sum(cap)) >= cap * g.max_deg:
+        return None
+    widths, m = [], min(cap, n_seeds)  # most members the hop can expand
+    while True:
+        widths.append(_ceil128(view.top_degree_sum(m)))
+        grown = min(cap, g.num_nodes, m + view.top_degree_sum(m))
+        if grown == m:
+            break
+        m = grown
+    return CSRGather(view.indptr, view.indices, tuple(widths))
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
@@ -85,23 +143,21 @@ def build_workset(
     max_hops: int,
     cap: int,
     use_kernel: bool = False,
+    csr: Optional[CSRGather] = None,  # CSR hop gather; None: the ELL one
 ) -> Workset:
     """Expand seeds into the capacity-``cap`` workset of the max_hops ball."""
     n = nbr.shape[0]
     ws_ids, ws_dist, overflow = _seed_workset(seeds, n, cap)
-
-    def hop(carry, h):
-        wi, wd, ov = carry
-        wi, wd, _, dropped = fe_ops.expand_hop(
-            wi, wd, nbr, nbr_mask, h + 1, band=max_hops + 2,
-            use_kernel=use_kernel,
+    for h in range(max_hops):
+        kw = {} if csr is None else dict(
+            csr=(csr.indptr, csr.indices),
+            width=csr.widths[min(h, len(csr.widths) - 1)],
         )
-        return (wi, wd, ov | dropped), None
-
-    (ws_ids, ws_dist, overflow), _ = jax.lax.scan(
-        hop, (ws_ids, ws_dist, overflow),
-        jnp.arange(max_hops, dtype=jnp.int32),
-    )
+        ws_ids, ws_dist, _, dropped = fe_ops.expand_hop(
+            ws_ids, ws_dist, nbr, nbr_mask, h + 1, band=max_hops + 2,
+            use_kernel=use_kernel, **kw,
+        )
+        overflow = overflow | dropped
     return Workset(ids=ws_ids, dist=ws_dist, overflow=overflow, num_nodes=n)
 
 

@@ -6,6 +6,11 @@ gathers index arrays of length ``num_nodes + 1`` whose last row is a neutral
 element, so frontier expansion / message passing are single fixed-shape
 gathers with no bounds checks.  High-degree tails beyond ``max_deg`` are
 truncated (documented; choose ``max_deg >= max degree`` for exactness).
+
+:func:`csr_to_ell` also attaches a :class:`CSRView` of exactly the edges the
+ELL keeps.  On a skewed graph ``K`` is set by a few hubs, so a workset hop
+that gathers ``K`` slots per member is mostly padding; the compact backend
+gathers real neighbours from the view instead (``repro.core.workset``).
 """
 from __future__ import annotations
 
@@ -19,6 +24,21 @@ from repro.graph.csr import CSRGraph
 
 
 @dataclasses.dataclass
+class CSRView:
+    """CSR of the edges an :class:`ELLGraph` keeps: row ``u`` holds
+    ``indices[indptr[u]:indptr[u+1]]``, the real slots of ``nbr[u]`` in
+    slot order."""
+
+    indptr: jnp.ndarray  # (N+1,) int32
+    indices: jnp.ndarray  # (nnz,) int32
+    deg_top: np.ndarray  # (N+1,) int64 host: sum of the m largest degrees
+
+    def top_degree_sum(self, m: int) -> int:
+        """Most neighbour slots any ``m`` distinct rows can hold."""
+        return int(self.deg_top[min(m, len(self.deg_top) - 1)])
+
+
+@dataclasses.dataclass
 class ELLGraph:
     """``nbr[i, k]`` = k-th neighbor of node i, or ``num_nodes`` (sentinel)."""
 
@@ -26,6 +46,7 @@ class ELLGraph:
     nbr_mask: jnp.ndarray  # (N, max_deg) bool — True where a real edge exists
     num_nodes: int
     node_feat: Optional[jnp.ndarray] = None  # (N, F)
+    csr: Optional[CSRView] = None  # set by csr_to_ell; None for other builds
 
     @property
     def max_deg(self) -> int:
@@ -42,7 +63,11 @@ class ELLGraph:
 def csr_to_ell(
     g: CSRGraph, max_deg: Optional[int] = None, *, pad_to_multiple: int = 8
 ) -> ELLGraph:
-    """Convert CSR → ELL, truncating rows above ``max_deg`` (host-side)."""
+    """Convert CSR → ELL, truncating rows above ``max_deg`` (host-side).
+
+    The result carries a :class:`CSRView` of the kept edges (rows truncated
+    alike), so both hop gathers of the compact backend see one graph.
+    """
     deg = g.degrees()
     if max_deg is None:
         max_deg = int(deg.max()) if g.num_nodes else 1
@@ -56,11 +81,22 @@ def csr_to_ell(
     rows = np.repeat(np.arange(n), take)
     slots = _ranges(take)
     src_pos = np.repeat(g.indptr[:-1], take) + slots
-    nbr[rows, slots] = g.indices[src_pos]
+    kept = g.indices[src_pos]
+    nbr[rows, slots] = kept
     mask = np.arange(max_deg)[None, :] < take[:, None]
     feat = jnp.asarray(g.node_feat) if g.node_feat is not None else None
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(take, out=indptr[1:])
+    deg_top = np.zeros(n + 1, np.int64)
+    np.cumsum(np.sort(take)[::-1], out=deg_top[1:])
+    csr = CSRView(
+        indptr=jnp.asarray(indptr, jnp.int32),
+        indices=jnp.asarray(kept, jnp.int32),
+        deg_top=deg_top,
+    )
     return ELLGraph(
-        nbr=jnp.asarray(nbr), nbr_mask=jnp.asarray(mask), num_nodes=n, node_feat=feat
+        nbr=jnp.asarray(nbr), nbr_mask=jnp.asarray(mask), num_nodes=n,
+        node_feat=feat, csr=csr,
     )
 
 

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro import configs as C
+from repro.core import graph_retrieval as gr
+from repro.core.workset import CSRGather
 from repro.kernels.bfs_frontier.kernel import frontier_hop_kernel
 from repro.kernels.frontier_expand.kernel import ws_mark_kernel
 from repro.kernels.topk_sim import ops as topk_ops
@@ -24,6 +26,9 @@ from repro.serving.engine import _prefill_batch
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
 ARXIV_NODES = 169_343
+# the benchmark's arxiv-scale corpus: directed edges, largest degree padded
+# to 8, and csr_gather's widths for 4 seeds and a workset of 2048
+ARXIV_EDGES, ARXIV_K, ARXIV_WIDTHS = 1_354_712, 1016, (3840, 164_480)
 
 
 @pytest.fixture(scope="module")
@@ -115,6 +120,21 @@ def test_prefill_compiles_at_published_width(lm, sds):
         functools.partial(_prefill_batch, cfg=cfg, cache_len=4096), params,
         sds((4, 512), jnp.int32), sds((4,), jnp.int32))
     assert _fits(compiled) > 8 * 2**30
+
+
+def test_compact_bfs_csr_gather_compiles(sds):
+    """Compact BFS with the CSR hop gather at the arxiv shape, 8 queries:
+    the (Q, C, K) ELL block (1.07 GB of temporaries) is gone."""
+    csr = CSRGather(sds((ARXIV_NODES + 1,), jnp.int32),
+                    sds((ARXIV_EDGES,), jnp.int32), ARXIV_WIDTHS)
+    op = functools.partial(gr.bfs_subgraph_compact, max_hops=3, max_nodes=64,
+                           workset_cap=2048)
+    compiled = _compile(
+        lambda nbr, msk, seeds, csr: op(nbr, msk, seeds, csr=csr),
+        sds((ARXIV_NODES, ARXIV_K), jnp.int32),
+        sds((ARXIV_NODES, ARXIV_K), jnp.bool_), sds((8, 4), jnp.int32), csr)
+    _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError, reason=(

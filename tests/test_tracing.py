@@ -2,6 +2,7 @@
 profiler session on the CPU: names, nesting, counts, stamp order, and the
 compile counter's attribution to the innermost span."""
 import contextlib
+import dataclasses
 import glob
 
 import jax
@@ -11,6 +12,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro import tracing
+from repro.core import graph_retrieval as gr
 from repro.core import BruteIndex, GraphTokenizer, PipelineConfig, \
     RGLPipeline, Vocab
 from repro.graph import csr_to_ell, generators
@@ -152,6 +154,25 @@ def test_stamps_use_the_engine_clock(stack):
     (out,) = eng.run_to_completion()
     assert out.submitted_at == 100.0
     assert out.launched_at == out.prompt_at == out.first_token_at == 101.0
+
+
+@pytest.mark.parametrize("mode,arm", [("compact", "csr"), ("dense", "dense")])
+def test_subgraph_span_names_its_gather(stack, tmp_path, mode, arm):
+    """``rgl.retrieval.subgraph`` carries the per-hop gather and its width."""
+    g, pipe, _, _ = stack
+    conf = dataclasses.replace(pipe.config, retrieval_mode=mode)
+    pipe = dataclasses.replace(pipe, config=conf)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(pipe.retrieve(jnp.asarray(g.node_feat[:3])).sub)
+    finally:
+        jax.profiler.stop_trace()
+    (sub,) = [s for s in _read_spans(tmp_path) if s[0] == "retrieval.subgraph"]
+    want = gr.hop_gather(pipe.graph, conf.k_seeds, conf.strategy, mode=mode,
+                         workset_cap=conf.workset_cap,
+                         max_nodes=conf.max_nodes)
+    assert want[0] == arm
+    assert (sub[3]["gather"], sub[3]["cand"]) == want
 
 
 @contextlib.contextmanager

@@ -13,8 +13,11 @@ import pytest
 
 from repro.core import graph_retrieval as gr
 from repro.core import naive
-from repro.core.workset import build_workset, workset_adjacency
-from repro.graph import CSRGraph, csr_to_ell, generators
+from repro.core.workset import (
+    CSRGather, _seed_workset, build_workset, csr_gather, workset_adjacency,
+)
+from repro.graph import CSRGraph, DeltaGraph, csr_to_ell, generators
+from repro.kernels.frontier_expand import ops as fe_ops
 
 STRAT_KW = {
     "bfs": dict(max_hops=3, max_nodes=40),
@@ -238,3 +241,180 @@ def test_filter_preserves_overflow_flags(graph):
     np.testing.assert_array_equal(
         np.asarray(out.overflow), np.asarray(sub.overflow)
     )
+
+
+# ------------------------------------------------- CSR vs ELL hop gather ----
+def _power_law_graph(seed, n=500, m=1200, alpha=1.25):
+    """Chung-Lu style: endpoints drawn with weight (i+1)**-alpha, so a few
+    hubs reach degrees 50-100x the mean."""
+    rng = np.random.default_rng(seed)
+    w = np.arange(1, n + 1, dtype=np.float64) ** -alpha
+    perm = rng.permutation(n)  # hubs at random ids, not at 0..k
+    src = perm[rng.choice(n, size=m, p=w / w.sum())]
+    dst = rng.integers(0, n, size=m)
+    pairs = np.unique(np.sort(np.stack([src, dst], 1), 1), axis=0)
+    pairs = pairs[pairs[:, 0] != pairs[:, 1]]
+    return CSRGraph.from_edges(pairs[:, 0], pairs[:, 1], n, symmetrize=True)
+
+
+def _regular_graph(n=300, half=4):
+    """Circulant graph: every node has the same 2*half = 8 neighbors."""
+    u = np.repeat(np.arange(n), half)
+    v = (u + np.tile(np.arange(1, half + 1), n)) % n
+    return CSRGraph.from_edges(u, v, n, symmetrize=True)
+
+
+GATHER_GRAPHS = {
+    "powerlaw": lambda: csr_to_ell(_power_law_graph(11)),
+    "powerlaw_truncated": lambda: csr_to_ell(_power_law_graph(12), max_deg=16),
+    "regular": lambda: csr_to_ell(_regular_graph()),
+}
+GATHER_HOPS = 3
+GATHER_KW = {
+    "bfs": dict(max_hops=GATHER_HOPS, max_nodes=16),
+    "dense": dict(max_hops=GATHER_HOPS, max_nodes=16),
+    "steiner": dict(max_hops=GATHER_HOPS, max_nodes=16),
+    "ppr": dict(n_iter=GATHER_HOPS, max_nodes=16),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(GATHER_GRAPHS))
+def gather_graph(request):
+    return request.param, GATHER_GRAPHS[request.param]()
+
+
+def _ell_adj(ell):
+    nbr, msk = np.asarray(ell.nbr), np.asarray(ell.nbr_mask)
+    return {u: nbr[u][msk[u]].tolist() for u in range(ell.num_nodes)}
+
+
+def _ball_sizes(ell, seeds, hops):
+    """(Q, hops + 1) ball sizes by radius, following the ELL's rows."""
+    adj = _ell_adj(ell)
+    out = np.zeros((len(seeds), hops + 1), int)
+    for qi, row in enumerate(seeds):
+        d = np.array(list(naive.bfs_distances(
+            adj, sorted(set(row.tolist())), hops).values()))
+        out[qi] = [(d <= h).sum() for h in range(hops + 1)]
+    return out
+
+
+def _cap_for(ell, seeds, overflow_at):
+    """A workset cap under which some query first overflows at hop
+    ``overflow_at`` (0: none does)."""
+    balls = _ball_sizes(ell, seeds, GATHER_HOPS)
+    if overflow_at == 0:
+        return int(balls[:, -1].max())
+    cap = max(16, int(balls[:, overflow_at - 1].max()))
+    assert (balls[:, overflow_at] > cap).any(), "no query overflows there"
+    return cap
+
+
+def _forced_csr(ell, cap):
+    """The CSR gather at one width for every hop, whatever it costs."""
+    e = -(-ell.csr.top_degree_sum(cap) // 128) * 128
+    return CSRGather(ell.csr.indptr, ell.csr.indices, (max(128, e),))
+
+
+@pytest.mark.parametrize("overflow_at", [0, 2, 3],
+                         ids=["no_overflow", "overflow_hop2", "overflow_hop3"])
+@pytest.mark.parametrize("strategy", sorted(gr.COMPACT_STRATEGIES))
+def test_csr_gather_matches_ell_gather(gather_graph, strategy, overflow_at):
+    """The CSR hop gather gives the ELL gather's workset and subgraph bit
+    for bit; it is chosen on skewed graphs and not on a regular one."""
+    name, ell = gather_graph
+    seeds = jnp.asarray(_seeds(ell.num_nodes, q=6, s=4, seed=21))
+    cap = _cap_for(ell, np.asarray(seeds), overflow_at)
+    chosen = csr_gather(ell, cap, seeds.shape[1])
+    assert (chosen is None) == (name == "regular")
+    arms = [None, _forced_csr(ell, cap)] + ([chosen] if chosen else [])
+    ws = [build_workset(ell.nbr, ell.nbr_mask, seeds, max_hops=GATHER_HOPS,
+                        cap=cap, csr=a) for a in arms]
+    subs = [gr.COMPACT_STRATEGIES[strategy](
+        ell.nbr, ell.nbr_mask, seeds, workset_cap=cap, csr=a,
+        **GATHER_KW[strategy]) for a in arms]
+    assert bool(np.asarray(ws[0].overflow).any()) == (overflow_at > 0)
+    for w, sub in zip(ws[1:], subs[1:]):
+        for f in ("ids", "dist", "overflow"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(w, f)), np.asarray(getattr(ws[0], f)), f)
+        _assert_bitwise_equal(sub, subs[0])
+        np.testing.assert_array_equal(np.asarray(sub.overflow),
+                                      np.asarray(subs[0].overflow))
+
+
+@pytest.mark.parametrize("max_deg", [None, 16], ids=["full", "truncated"])
+def test_csr_view_holds_the_kept_edges(max_deg):
+    ell = csr_to_ell(_power_law_graph(13), max_deg=max_deg)
+    nbr, msk = np.asarray(ell.nbr), np.asarray(ell.nbr_mask)
+    indptr, indices = np.asarray(ell.csr.indptr), np.asarray(ell.csr.indices)
+    assert indices.size == msk.sum()
+    for u in range(ell.num_nodes):
+        np.testing.assert_array_equal(indices[indptr[u]:indptr[u + 1]],
+                                      nbr[u][msk[u]])
+
+
+@pytest.mark.parametrize("name", ["powerlaw", "powerlaw_truncated"])
+def test_csr_width_bounds_every_hop(name):
+    """The widest E is the sum of the C largest degrees, rounded up to 128,
+    and no hop proposes more real neighbors than its own width."""
+    ell = GATHER_GRAPHS[name]()
+    seeds = jnp.asarray(_seeds(ell.num_nodes, q=6, s=4, seed=22))
+    deg = np.asarray(ell.degrees())
+    degs = np.sort(deg)[::-1]
+    chosen = 0
+    for cap in (16, 64, 256):
+        e = max(128, -(-int(degs[:cap].sum()) // 128) * 128)
+        g = csr_gather(ell, cap, seeds.shape[1])
+        if g is None:  # E no narrower than the ELL gather's C*K
+            assert e >= cap * ell.max_deg
+            continue
+        chosen += 1
+        assert g.width == e
+        assert g.widths[0] == max(128, -(-int(degs[:4].sum()) // 128) * 128)
+        wi, wd, _ = _seed_workset(seeds, ell.num_nodes, cap)
+        for h in range(GATHER_HOPS):
+            width = g.widths[min(h, len(g.widths) - 1)]
+            real = max(deg[r[r < ell.num_nodes]].sum() for r in np.asarray(wi))
+            assert real <= width, (cap, h)
+            wi, wd, _, _ = fe_ops.expand_hop(
+                wi, wd, ell.nbr, ell.nbr_mask, h + 1, band=GATHER_HOPS + 2,
+                csr=(g.indptr, g.indices), width=width)
+    assert chosen
+
+
+def test_merged_delta_view_takes_the_ell_gather():
+    """A mutation store's merged view has no CSR view: the ELL gather runs,
+    and the compact backend still matches the dense one on it."""
+    ell = csr_to_ell(_power_law_graph(14, n=200, m=500))
+    d = DeltaGraph(np.asarray(ell.nbr), np.asarray(ell.nbr_mask),
+                   ell.num_nodes, ell.num_nodes + 8, extra_deg=4)
+    v = int(np.asarray(ell.nbr)[0, 0])
+    for a, b in ((3, 150), (150, 3)):  # the graph stays symmetric
+        d.add_edge(a, b)
+    for a, b in ((0, v), (v, 0)):
+        d.del_edge(a, b)
+    merged = d.merged()
+    assert merged.csr is None
+    seeds = _seeds(ell.num_nodes, q=4, seed=23)
+    kw = dict(max_hops=2, max_nodes=16)
+    arm = gr.hop_gather(merged, 4, "bfs", mode="compact", workset_cap=512,
+                        **kw)
+    assert arm == ("ell", 512 * merged.max_deg)
+    comp = gr.retrieve_subgraph(merged, seeds, "bfs", mode="compact",
+                                workset_cap=512, **kw)
+    dense = gr.retrieve_subgraph(merged, seeds, "bfs", mode="dense", **kw)
+    assert not np.asarray(comp.overflow).any()
+    _assert_bitwise_equal(comp, dense)
+
+
+def test_hop_gather_names_the_arm(gather_graph):
+    name, ell = gather_graph
+    kw = dict(mode="compact", workset_cap=64, max_nodes=16)
+    arm, width = gr.hop_gather(ell, 4, "bfs", **kw)
+    if name == "regular":
+        assert (arm, width) == ("ell", 64 * ell.max_deg)
+    else:
+        assert (arm, width) == ("csr", csr_gather(ell, 64, 4).width)
+    assert gr.hop_gather(ell, 4, "bfs", mode="dense") == \
+        ("dense", ell.num_nodes * ell.max_deg)
