@@ -43,7 +43,6 @@ def main(argv=None) -> int:
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     from bench import harness as H
-    from bench import weights
 
     cell = H.load_cell(args.workload)
     if jax.devices()[0].platform != "tpu":
@@ -57,7 +56,7 @@ def main(argv=None) -> int:
         else:
             b.params = None
             gc.collect()
-            b.params = weights.make_params(cell.config["model"], seed)
+            b.params = cell.family.make_params(cell.config["model"], seed)
         row = reading(cell, b, seed, args.seconds)
         rows.append(row)
         print(json.dumps(row), flush=True)
@@ -83,7 +82,7 @@ def reading(cell, b, seed: int, seconds: float) -> dict:
     warm = traffic.Stream(mix, b.corpus.feat, seed, warmup=True)
     max_new = stream.max_new()
     eng = H.make_engine(cell, b, max_new)
-    rec = H.Recorder(eng, cell.config["model"], b.texts)
+    rec = H.Recorder(eng, cell, b.texts)
     H.warm_up(rec, warm, mix)
     H.serve_window(rec, stream, mix, seconds)
     recs = [r for r in rec.done if r.in_window]

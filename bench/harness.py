@@ -2,10 +2,11 @@
 warm it up, serve its traffic for a window, check what was served, and
 reduce the result to the cell's metrics.
 
-Everything that belongs to one configuration, traffic mix or metric lives
-in a file of its own, found by the names in ``BENCHMARK.json``:
-``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` and
-``bench/metrics/<metric>.py``.
+Everything that belongs to one configuration, traffic mix, metric or model
+family lives in a file of its own, found by the names in ``BENCHMARK.json``
+and in the configuration: ``bench/configs/<config>.json``,
+``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py`` and
+``bench/models/<architecture>.py`` (``model["architectures"][0]``).
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ from pathlib import Path
 import numpy as np
 
 from bench import corpus as corpus_mod
-from bench import flops, reference, traffic, weights
+from bench import models, reference, traffic
 from bench import trace as tr
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -46,6 +47,7 @@ class Cell:
     chips: int
     end_to_end: list  # BENCHMARK.json entries that this cell reports
     per_layer: list
+    family: object  # bench/models/<architecture>.py, as a module
 
 
 def _reports(metric: dict, cell: str, e2e_names: set) -> bool:
@@ -67,8 +69,9 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     e2e = [m for m in spec["end_to_end"] if _reports(m, name, set())]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"] if _reports(m, name, names)]
+    fam = models.family(config["model"], root / "bench" / "models")
     return Cell(name=name, config=config, mix=mix, chips=int(w["chips"]),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, family=fam)
 
 
 def metric_reader(name: str, metrics_dir: Path = BENCH_DIR / "metrics"):
@@ -84,23 +87,6 @@ def metric_reader(name: str, metrics_dir: Path = BENCH_DIR / "metrics"):
 # --------------------------------------------------------------------------
 # building the system under test
 # --------------------------------------------------------------------------
-def transformer_config(model: dict, name: str):
-    """The program's config type, filled from the configuration file."""
-    from repro.models.transformer.config import TransformerConfig
-
-    eps = model.get("rms_norm_eps", model.get("norm_epsilon"))
-    return TransformerConfig(
-        name=name, n_layers=int(model["num_hidden_layers"]),
-        d_model=int(model["hidden_size"]),
-        n_heads=int(model["num_attention_heads"]),
-        n_kv_heads=int(model["num_key_value_heads"]),
-        d_head=int(model["head_dim"]), d_ff=int(model["intermediate_size"]),
-        vocab=int(model["vocab_size"]), rope_theta=float(model["rope_theta"]),
-        sliding_window=model.get("sliding_window"), norm_eps=float(eps),
-        dtype=str(model["torch_dtype"]),
-    )
-
-
 @dataclasses.dataclass
 class Built:
     corpus: object
@@ -140,12 +126,12 @@ def build(cell: Cell, seed: int, corpus_dir=None) -> Built:
     tok = serve.graph_tokenizer(g, max_len=s["prompt_cap"],
                                 node_budget=s["node_budget"])
     pipe = serve.build_rag_pipeline(g, ell, emb, pcfg, tok)
-    cfg = transformer_config(conf["model"], conf["name"])
+    cfg = cell.family.program_config(conf["model"], conf["name"])
     if tok.vocab.size > cfg.vocab:
         raise ValueError(f"tokenizer ids reach {tok.vocab.size - 1}, past "
                          f"the vocabulary of {cfg.vocab}")
     t = time.perf_counter()
-    params = weights.make_params(conf["model"], seed)
+    params = cell.family.make_params(conf["model"], seed)
     jax.block_until_ready(params)
     say(f"setup: weights {sum(x.size for x in jax.tree.leaves(params))} "
         f"parameters ({time.perf_counter() - t:.2f}s)")
@@ -189,9 +175,10 @@ class Recorder:
     """Per-request stamps on the host clock, and the useful work served,
     counted from the tokens each request gains at every engine step."""
 
-    def __init__(self, eng, model: dict, texts: list):
+    def __init__(self, eng, cell: Cell, texts: list):
         self.eng = eng
-        self.model = model
+        self.model = cell.config["model"]
+        self.family = cell.family
         self.texts = texts
         self.live: dict = {}  # uid -> Rec, submitted and not yet returned
         self.done: list = []
@@ -218,11 +205,12 @@ class Recorder:
         plen = len(rec.req.prompt_ids)
         if rec.n_tokens == 0:
             rec.first_at = now
-            self.flops += flops.prefill_flops(self.model, plen)
+            self.flops += self.family.prefill_flops(self.model, plen)
             self.prefills += 1
             self.prompt_tokens += plen
         for t in range(max(rec.n_tokens, 1), n):
-            self.flops += flops.decode_flops(self.model, plen + t - 1)
+            self.flops += self.family.decode_flops(self.model,
+                                                    plen + t - 1)
         rec.n_tokens = n
 
     def step(self) -> list:
@@ -436,12 +424,12 @@ def lm_gaps(cell: Cell, params, recs: list, max_new: int,
             control: bool = False):
     """Widest served-token gap below the reference's best (and the
     control's, when asked) over the sampled requests."""
-    model = cell.config["model"]
-    hp = reference.hparams(model)
+    hp = cell.family.hparams(cell.config["model"])
     pad = pad_len(cell.config, max_new)
     worst, worst_c, n = 0.0, 0.0, 0
     for r in recs:
-        g, gc_ = reference.request_gaps(params, hp, r.req.prompt_ids,
+        g, gc_ = reference.request_gaps(cell.family, params, hp,
+                                        r.req.prompt_ids,
                                         r.req.out_tokens, pad,
                                         quant_control=control)
         worst = max(worst, float(g.max()))

@@ -1,19 +1,18 @@
 """Plain references that decide ``correct``.  They import nothing of the
 program and take nothing it made.
 
-* :func:`lm_logits` -- the decoder's forward pass in float32 at ``highest``
-  matmul precision, written from the architecture's equations (RMSNorm,
-  rotary embeddings, grouped-query causal attention with an optional
-  sliding window, gated SiLU MLP, untied output head).  ``quant=True``
+* :func:`request_gaps` -- served tokens scored by the model family's
+  ``lm_logits`` (``bench/models/<architecture>.py``): the decoder's
+  forward pass in float32 at ``highest`` matmul precision, written from
+  the architecture's equations.  With ``quant=True`` every family's pass
   is the control, one precision below the bfloat16 the configurations
-  serve in: the same pass with every linear layer's weights and inputs
-  rounded to float8 e4m3 (scaled per output channel and per token).
+  serve in: each linear layer through :func:`linear`, its weights and
+  inputs rounded to float8 e4m3 (scaled per output channel and per token).
 * :class:`RetrievalReference` -- index, compact BFS, filter and
   linearization on the host in float64 / exact integers.
 """
 from __future__ import annotations
 
-import functools
 from collections import Counter
 
 import jax
@@ -24,18 +23,8 @@ HI = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
-# language model
+# language model: the float8 control and the served-token comparison
 # --------------------------------------------------------------------------
-def hparams(model: dict) -> tuple:
-    """Static hyper-parameters of :func:`lm_logits` from a configuration's
-    ``model`` block."""
-    eps = model.get("rms_norm_eps", model.get("norm_epsilon"))
-    return (int(model["num_attention_heads"]),
-            int(model["num_key_value_heads"]), int(model["head_dim"]),
-            float(model["rope_theta"]), model.get("sliding_window"),
-            float(eps))
-
-
 E4M3_MAX = 448.0  # largest finite float8 e4m3 value
 E4M3_MIN_EXP = -6  # smallest normal exponent; below it the step is fixed
 
@@ -52,62 +41,12 @@ def _f8(x, axis):
     return y * s
 
 
-def _linear(x, w, quant):
+def linear(x, w, quant: bool):
+    """``x @ w`` at ``highest`` precision; with ``quant`` the control's
+    float8 rounding of both, weights per output channel, inputs per row."""
     if quant:
         x, w = _f8(x, -1), _f8(w, 0)
     return jnp.matmul(x, w, precision=HI)
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, theta):
-    """x (S, H, dh): rotate the two halves of each head by position."""
-    s, _, dh = x.shape
-    half = dh // 2
-    inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / dh)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * jnp.asarray(
-        inv, jnp.float32)[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-@functools.partial(jax.jit, static_argnames=("hp", "quant"))
-def lm_logits(params, tokens, hp: tuple, quant: bool = False):
-    """tokens (S,) int32 -> (S, V) float32 logits of the next token."""
-    n_heads, n_kv, dh, theta, window, eps = hp
-    s = tokens.shape[0]
-    rep = n_heads // n_kv
-    i = jnp.arange(s)[:, None]
-    j = jnp.arange(s)[None, :]
-    allowed = j <= i
-    if window is not None:
-        allowed &= (i - j) < window
-    emb = params["embed"].astype(jnp.float32)
-    x = emb[tokens]
-
-    def layer(x, p):
-        p = jax.tree.map(lambda a: a.astype(jnp.float32), p)
-        h = _rms(x, p["ln1"], eps)
-        q = _rope(_linear(h, p["wq"], quant).reshape(s, n_heads, dh), theta)
-        k = _rope(_linear(h, p["wk"], quant).reshape(s, n_kv, dh), theta)
-        v = _linear(h, p["wv"], quant).reshape(s, n_kv, dh)
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-        sc = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) / np.sqrt(dh)
-        sc = jnp.where(allowed[None], sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", pr, v, precision=HI).reshape(s, -1)
-        x = x + _linear(o, p["wo"], quant)
-        h = _rms(x, p["ln2"], eps)
-        g = jax.nn.silu(_linear(h, p["w1"], quant)) * _linear(h, p["w3"], quant)
-        return x + _linear(g, p["w2"], quant), None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    x = _rms(x, params["ln_f"].astype(jnp.float32), eps)
-    return _linear(x, params["head"].astype(jnp.float32), quant)
 
 
 @jax.jit
@@ -137,19 +76,20 @@ def served_rows(prompt_len: int, n_out: int, pad_to: int):
     return length, np.arange(prompt_len - 1, prompt_len - 1 + n_out)
 
 
-def request_gaps(params, hp, prompt, out, pad_to: int, quant_control=False):
-    """Served-token gaps of one request (and the control's gaps at the same
-    rows when ``quant_control``)."""
+def request_gaps(family, params, hp, prompt, out, pad_to: int,
+                 quant_control=False):
+    """Served-token gaps of one request under ``family``'s reference (and
+    the control's gaps at the same rows when ``quant_control``)."""
     out = np.asarray(out, np.int32)
     length, rows = served_rows(len(prompt), len(out), pad_to)
     seq = np.zeros(pad_to, np.int32)
     seq[:len(prompt)] = prompt
     seq[len(prompt):length] = out[:-1]
-    ref = lm_logits(params, jnp.asarray(seq), hp)
+    ref = family.lm_logits(params, jnp.asarray(seq), hp)
     gaps = np.asarray(token_gaps(ref, jnp.asarray(rows), jnp.asarray(out)))
     if not quant_control:
         return gaps, None
-    ctl = lm_logits(params, jnp.asarray(seq), hp, quant=True)
+    ctl = family.lm_logits(params, jnp.asarray(seq), hp, quant=True)
     return gaps, np.asarray(control_gaps(ref, ctl, jnp.asarray(rows)))
 
 

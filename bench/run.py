@@ -93,7 +93,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
     warm = traffic.Stream(mix, b.corpus.feat, seed, warmup=True)
     max_new = stream.max_new()
     eng = H.make_engine(cell, b, max_new)
-    rec = H.Recorder(eng, cell.config["model"], b.texts)
+    rec = H.Recorder(eng, cell, b.texts)
     t = time.perf_counter()
     H.warm_up(rec, warm, mix)
     H.say(f"setup: warm-up {mix['warmup_requests']} requests "

@@ -47,7 +47,7 @@ def main(argv=None) -> int:
         mix = dict(cell.mix, rate_rps=rate)
         stream = traffic.Stream(mix, b.corpus.feat, args.seed + i)
         eng = H.make_engine(cell, b, stream.max_new())
-        rec = H.Recorder(eng, cell.config["model"], b.texts)
+        rec = H.Recorder(eng, cell, b.texts)
         H.warm_up(rec, traffic.Stream(mix, b.corpus.feat, args.seed + i,
                                       warmup=True), mix)
         win = H.serve_window(rec, stream, mix, args.seconds)

@@ -2,11 +2,12 @@
 table of peaks."""
 import pytest
 
-from bench import flops
+from bench import flops, models
 
+llama = models.family({"architectures": ["LlamaForCausalLM"]})
 TINY = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
         "num_key_value_heads": 1, "head_dim": 4, "intermediate_size": 16,
-        "vocab_size": 10}
+        "vocab_size": 10, "torch_dtype": "bfloat16"}
 
 
 def test_topk_sim_hand_count():
@@ -20,23 +21,23 @@ def test_topk_sim_hand_count():
 def test_decoder_token_flops_hand_count():
     # per layer: wq 8x8 + wk 8x4 + wv 8x4 + wo 8x8 + 3 x 8x16 = 576;
     # 2 layers + head 8x10 = 1232 weights, 2 flops each
-    assert flops.matmul_params(TINY) == 1232
-    assert flops.token_flops(TINY) == 2464
+    assert llama.matmul_params(TINY) == 1232
+    assert llama.token_flops(TINY) == 2464
 
 
 def test_attention_and_prefill_hand_count():
     # one query over 3 keys: 2 layers x 2 heads x (2 x 3 x 4) x 2
-    assert flops.attn_flops(TINY, 3) == 192
+    assert llama.attn_flops(TINY, 3) == 192
     # prefill of 3 tokens sees 1 + 2 + 3 keys
-    assert flops.prefill_flops(TINY, 3) == 3 * 2464 + 64 * 6
-    assert flops.decode_flops(TINY, 2) == 2464 + 192
+    assert llama.prefill_flops(TINY, 3) == 3 * 2464 + 64 * 6
+    assert llama.decode_flops(TINY, 2) == 2464 + 192
 
 
 def test_sliding_window_caps_keys():
     m = dict(TINY, sliding_window=2)
-    assert flops.attn_flops(m, 5) == flops.attn_flops(TINY, 2)
+    assert llama.attn_flops(m, 5) == llama.attn_flops(TINY, 2)
     # 4 tokens under a window of 2: keys 1 + 2 + 2 + 2
-    assert flops.prefill_flops(m, 4) == 4 * 2464 + 64 * 7
+    assert llama.prefill_flops(m, 4) == 4 * 2464 + 64 * 7
 
 
 def test_roofline_names_its_bound():

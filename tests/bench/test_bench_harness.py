@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from bench import harness as H
+from bench import models
 from benchutil import ROOT, tiny_cell
 
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -94,15 +95,14 @@ def test_control_comes_out_incorrect(seed, tmp_path):
 def _control_tokens(params, cell, rec, max_new):
     """Greedy tokens of the float8 control for one served request's prompt,
     as many as it was served."""
-    from bench import reference
-
-    hp = reference.hparams(cell.config["model"])
+    hp = cell.family.hparams(cell.config["model"])
     plen, n = len(rec.req.prompt_ids), len(rec.req.out_tokens)
     seq = np.zeros(H.pad_len(cell.config, max_new), np.int32)
     seq[:plen] = rec.req.prompt_ids
     out = []
     for i in range(n):
-        logits = reference.lm_logits(params, jnp.asarray(seq), hp, quant=True)
+        logits = cell.family.lm_logits(params, jnp.asarray(seq), hp,
+                                       quant=True)
         out.append(int(jnp.argmax(logits[plen - 1 + i])))
         seq[plen + i] = out[-1]
     return out
@@ -169,17 +169,36 @@ def test_cli_refuses_a_cpu():
     assert "no TPU" in p.stderr
 
 
-def test_cells_configs_mixes_and_metrics_are_found_by_name(tmp_path):
-    bench = tmp_path / "bench"
-    for d in ("configs", "traffic", "metrics"):
-        (bench / d).mkdir(parents=True)
+# a family file that a later configuration could add: another architecture
+# name, served by the program's Llama block
+TOY_FAMILY = """
+from bench.models import family
+
+_llama = family({"architectures": ["LlamaForCausalLM"]})
+program_config = _llama.program_config
+make_params = _llama.make_params
+hparams = _llama.hparams
+lm_logits = _llama.lm_logits
+prefill_flops = _llama.prefill_flops
+
+
+def decode_flops(model, position):
+    return 7 + _llama.decode_flops(model, position)
+"""
+
+
+def _bench_tree(root, config: dict, mix: dict):
+    """A BENCHMARK.json with one cell, ``new-model.new-mix``, under
+    ``root``, and the configuration, mix and metric files it names."""
+    bench = root / "bench"
+    for d in ("configs", "traffic", "metrics", "models"):
+        (bench / d).mkdir(parents=True, exist_ok=True)
     (bench / "configs" / "new-model.json").write_text(json.dumps(
-        {"name": "new-model", "model": {}}))
-    (bench / "traffic" / "new-mix.json").write_text(json.dumps(
-        {"loop": "closed", "clients": 2}))
+        dict(config, name="new-model")))
+    (bench / "traffic" / "new-mix.json").write_text(json.dumps(mix))
     (bench / "metrics" / "new_metric.cell.py").write_text(
         "def read(run):\n    return 42.0\n")
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+    (root / "BENCHMARK.json").write_text(json.dumps({
         "configs": [{"name": "new-model",
                      "file": "bench/configs/new-model.json"}],
         "workloads": [{"name": "new-model.new-mix", "config": "new-model",
@@ -193,15 +212,65 @@ def test_cells_configs_mixes_and_metrics_are_found_by_name(tmp_path):
                        "moves": "tokens_per_s"},
                       {"name": "ttft_only", "unit": "%",
                        "moves": "ttft_p95_ms"}]}))
+    return bench
+
+
+def test_cells_configs_mixes_and_metrics_are_found_by_name(tmp_path):
+    bench = _bench_tree(tmp_path,
+                        {"model": {"architectures": ["NewForCausalLM"]}},
+                        {"loop": "closed", "clients": 2})
+    (bench / "models" / "NewForCausalLM.py").write_text(
+        "def program_config(model, name):\n    return name\n")
     cell = H.load_cell("new-model.new-mix", root=tmp_path)
     assert cell.config["name"] == "new-model"
     assert cell.mix["clients"] == 2
     assert [m["name"] for m in cell.end_to_end] == ["setup_s", "tokens_per_s"]
     assert [m["name"] for m in cell.per_layer] == ["new_metric.cell"]
+    assert cell.family.program_config({}, "x") == "x"
     read = H.metric_reader("new_metric.cell", bench / "metrics")
     assert read(None) == 42.0
     with pytest.raises(KeyError):
         H.load_cell("missing", root=tmp_path)
+
+
+def test_model_family_is_found_by_architecture(tiny_config, tmp_path):
+    """A family file added under a new architecture name is found, built,
+    served, counted and checked with no edit to the harness."""
+    import bench.run as R
+    from benchutil import FIXTURES
+
+    model = dict(tiny_config["model"], architectures=["ToyForCausalLM"])
+    bench = _bench_tree(
+        tmp_path, dict(tiny_config, model=model),
+        json.loads((FIXTURES / "tiny-closed.json").read_text()))
+    (bench / "models" / "ToyForCausalLM.py").write_text(TOY_FAMILY)
+    cell = H.load_cell("new-model.new-mix", root=tmp_path)
+    assert cell.family.__file__ == str(bench / "models" / "ToyForCausalLM.py")
+    out = R.run(cell, SEED, 1.5, False, jax.devices(), PEAKS,
+                corpus_dir=tmp_path / "corpus")
+    assert out["correct"] is True, out["checks"]
+    assert out["checks"]["logit_gap_max"]["value"] < 1e-3
+    # served work is counted by the cell's family: a 10-token prompt and
+    # two decoded tokens
+    rec = H.Recorder(None, cell, [])
+    req = type("Req", (), {"prompt_ids": [0] * 10})()
+    rec._gain(H.Rec(uid=0, spec=None, req=req, due=0.0, in_window=True),
+              3, 0.0)
+    llama = models.family(tiny_config["model"])
+    assert rec.flops == llama.prefill_flops(model, 10) + sum(
+        llama.decode_flops(model, p) + 7 for p in (10, 11))
+
+
+@pytest.mark.parametrize("model", [{}, {"architectures": ["NoSuchLM"]}],
+                         ids=["missing", "unknown"])
+def test_unnamed_or_unknown_architecture_is_refused(model, tmp_path):
+    bench = _bench_tree(tmp_path, {"model": model},
+                        {"loop": "closed", "clients": 2})
+    (bench / "models" / "ToyForCausalLM.py").write_text(TOY_FAMILY)
+    with pytest.raises(KeyError, match=r"known: \['ToyForCausalLM'\]"):
+        H.load_cell("new-model.new-mix", root=tmp_path)
+    with pytest.raises(KeyError, match="LlamaForCausalLM"):
+        models.family(model)
 
 
 def test_every_listed_metric_has_a_reader():

@@ -1,61 +1,40 @@
 """The plain references against the program at a tiny size (CPU)."""
-import dataclasses
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, weights
+from bench import models, reference
 from bench import harness as H
-
-
-def _model(tiny_config, **kw):
-    return dict(tiny_config["model"], **kw)
-
-
-@pytest.mark.parametrize("window", [4096, 8], ids=["full", "window8"])
-def test_lm_reference_matches_the_program_forward(tiny_config, window):
-    from repro.models.transformer import model as tm
-
-    model = _model(tiny_config, sliding_window=window)
-    cfg = H.transformer_config(model, "tiny")
-    params = weights.make_params(model, 3)
-    toks = np.random.default_rng(0).integers(6, 1000, 64).astype(np.int32)
-    prog = np.asarray(tm.lm_logits(params, jnp.asarray(toks)[None], cfg))[0]
-    ref = np.asarray(reference.lm_logits(params, jnp.asarray(toks),
-                                         reference.hparams(model)))
-    assert np.abs(prog - ref).max() < 1e-3
-    ctl = np.asarray(reference.lm_logits(params, jnp.asarray(toks),
-                                         reference.hparams(model), quant=True))
-    assert np.abs(ctl - ref).max() > 1e-2
 
 
 def test_served_gaps_score_the_right_rows(tiny_config):
     model = tiny_config["model"]
-    params = weights.make_params(model, 4)
-    hp = reference.hparams(model)
+    fam = models.family(model)
+    params = fam.make_params(model, 4)
+    hp = fam.hparams(model)
     prompt = np.arange(6, 40, dtype=np.int32)
     # greedy continuation from the reference itself: every gap is 0
     seq = list(prompt)
     for _ in range(5):
-        lg = reference.lm_logits(params, jnp.asarray(np.array(seq, np.int32)),
-                                 hp)
+        lg = fam.lm_logits(params, jnp.asarray(np.array(seq, np.int32)), hp)
         seq.append(int(np.argmax(np.asarray(lg)[-1])))
     out = seq[len(prompt):]
-    gaps, ctl = reference.request_gaps(params, hp, prompt, out, pad_to=128,
-                                       quant_control=True)
+    gaps, ctl = reference.request_gaps(fam, params, hp, prompt, out,
+                                       pad_to=128, quant_control=True)
     assert gaps.shape == (5,) and np.abs(gaps).max() < 1e-4
     assert ctl.shape == (5,) and (ctl >= 0).all()
     bad = list(out)
     bad[2] = (bad[2] + 1) % 2048
-    gaps_bad, _ = reference.request_gaps(params, hp, prompt, bad, pad_to=128)
+    gaps_bad, _ = reference.request_gaps(fam, params, hp, prompt, bad,
+                                         pad_to=128)
     assert gaps_bad[2] > 1e-3
 
 
 @pytest.fixture(scope="module")
 def tiny_stack(tmp_path_factory, tiny_config):
     cell = H.Cell(name="tiny", config=tiny_config, mix={}, chips=1,
-                  end_to_end=[], per_layer=[])
+                  end_to_end=[], per_layer=[],
+                  family=models.family(tiny_config["model"]))
     b = H.build(cell, seed=5, corpus_dir=tmp_path_factory.mktemp("corpus"))
     ref = reference.RetrievalReference(
         b.corpus, b.texts, tiny_config["retrieval"], tiny_config["serving"],
