@@ -9,10 +9,12 @@ blocking the Pallas flash kernel (repro.kernels.flash_attn) uses on TPU.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG = -1e30
 
@@ -29,6 +31,44 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     return out.astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies (float64, ``dim // 2`` of them): the
+    original ``theta ** (-2i / dim)`` for fast-turning dims, the same divided
+    by ``factor`` for slow ones, and a linear ramp between the dims that
+    turn ``beta_fast`` and ``beta_slow`` times over ``original_max``."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor <= 1:
+        return extra
+
+    def corr(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_freqs(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
+               mscale: float = 1.0) -> jnp.ndarray:
+    """:func:`rope` with given frequencies (``dh // 2``) and a factor on the
+    cos/sin tables.  x: (..., S, H, dh); positions: broadcastable to
+    (..., S)."""
+    half = x.shape[-1] // 2
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
 def _tile_mask(qi, kj, cq, ck, window):
     """(Cq, Ck) causal/windowed mask for tile at q-offset qi, kv-offset kj."""
     iq = qi + jax.lax.broadcasted_iota(jnp.int32, (cq, ck), 0)
@@ -40,17 +80,19 @@ def _tile_mask(qi, kj, cq, ck, window):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "q_chunk", "kv_chunk", "use_kernel")
+    jax.jit,
+    static_argnames=("window", "q_chunk", "kv_chunk", "use_kernel", "scale"),
 )
 def chunked_attention(
     q: jnp.ndarray,  # (B, S, H, dh)
     k: jnp.ndarray,  # (B, S, KV, dh)
-    v: jnp.ndarray,  # (B, S, KV, dh)
+    v: jnp.ndarray,  # (B, S, KV, dv)
     *,
     window: Optional[int] = None,
     q_chunk: int = 512,
     kv_chunk: int = 512,
     use_kernel: bool = False,
+    scale: Optional[float] = None,  # None = dh ** -0.5
 ) -> jnp.ndarray:
     """Differentiable flash attention (custom VJP).
 
@@ -60,6 +102,7 @@ def chunked_attention(
     §Perf iteration 0).  The custom backward recomputes tiles from the saved
     (q, k, v, o, logsumexp) instead — the FlashAttention-2 bwd schedule."""
     if use_kernel:
+        assert scale is None and v.shape[-1] == q.shape[-1]
         from repro.kernels.flash_attn import ops as fa_ops
 
         return fa_ops.flash_attention(
@@ -69,37 +112,39 @@ def chunked_attention(
     cq = min(q_chunk, s)
     ck = min(kv_chunk, s)
     assert s % cq == 0 and s % ck == 0, (s, cq, ck)
-    return _flash(window, cq, ck)(q, k, v)
+    return _flash(window, cq, ck, scale)(q, k, v)
 
 
 @functools.lru_cache(maxsize=None)
-def _flash(window, cq, ck):
-    """custom_vjp flash attention specialized to (window, q_chunk, kv_chunk)."""
+def _flash(window, cq, ck, scale=None):
+    """custom_vjp flash attention specialized to (window, q_chunk, kv_chunk,
+    softmax scale)."""
 
     @jax.custom_vjp
     def fn(q, k, v):
-        return _flash_fwd(q, k, v, window, cq, ck)[0]
+        return _flash_fwd(q, k, v, window, cq, ck, scale)[0]
 
     def fwd(q, k, v):
-        o, lse = _flash_fwd(q, k, v, window, cq, ck)
+        o, lse = _flash_fwd(q, k, v, window, cq, ck, scale)
         return o, (q, k, v, o, lse)
 
     def bwd(res, do):
-        return _flash_bwd(res, do, window, cq, ck)
+        return _flash_bwd(res, do, window, cq, ck, scale)
 
     fn.defvjp(fwd, bwd)
     return fn
 
 
-def _flash_fwd(q, k, v, window, cq, ck):
+def _flash_fwd(q, k, v, window, cq, ck, scale=None):
     b, s, h, dh = q.shape
     kvh = k.shape[2]
+    dv = v.shape[-1]
     rep = h // kvh
     nq, nk = s // cq, s // ck
-    scale = dh**-0.5
+    scale = dh**-0.5 if scale is None else scale
     qg = q.reshape(b, nq, cq, kvh, rep, dh)
     kg = k.reshape(b, nk, ck, kvh, dh)
-    vg = v.reshape(b, nk, ck, kvh, dh)
+    vg = v.reshape(b, nk, ck, kvh, dv)
 
     def q_block(carry, qi):
         qb = qg[:, qi]  # (B, Cq, KV, rep, dh)
@@ -124,7 +169,7 @@ def _flash_fwd(q, k, v, window, cq, ck):
 
         m0 = jnp.full((b, kvh, rep, cq), NEG, jnp.float32)
         l0 = jnp.zeros((b, kvh, rep, cq), jnp.float32)
-        o0 = jnp.zeros((b, kvh, rep, cq, dh), jnp.float32)
+        o0 = jnp.zeros((b, kvh, rep, cq, dv), jnp.float32)
         (m, l, o), _ = jax.lax.scan(
             kv_block, (m0, l0, o0), jnp.arange(nk, dtype=jnp.int32)
         )
@@ -133,28 +178,29 @@ def _flash_fwd(q, k, v, window, cq, ck):
         return carry, (out, lse)
 
     _, (outs, lses) = jax.lax.scan(q_block, None, jnp.arange(nq, dtype=jnp.int32))
-    # outs: (nq, B, KV, rep, Cq, dh) -> (B, S, H, dh)
-    out = jnp.moveaxis(outs, 0, 1)  # (B, nq, KV, rep, Cq, dh)
-    out = jnp.transpose(out, (0, 1, 4, 2, 3, 5)).reshape(b, s, h, dh)
+    # outs: (nq, B, KV, rep, Cq, dv) -> (B, S, H, dv)
+    out = jnp.moveaxis(outs, 0, 1)  # (B, nq, KV, rep, Cq, dv)
+    out = jnp.transpose(out, (0, 1, 4, 2, 3, 5)).reshape(b, s, h, dv)
     lse = jnp.moveaxis(lses, 0, 1)  # (B, nq, KV, rep, Cq)
     return out, lse
 
 
-def _flash_bwd(res, do, window, cq, ck):
+def _flash_bwd(res, do, window, cq, ck, scale=None):
     """FlashAttention-2 backward: recompute score tiles from (q,k,v,lse);
     pass 1 accumulates dq over kv blocks, pass 2 accumulates (dk, dv) over
     q blocks.  Live memory = one tile + the output grads."""
     q, k, v, o, lse = res
     b, s, h, dh = q.shape
     kvh = k.shape[2]
+    d_v = v.shape[-1]
     rep = h // kvh
     nq, nk = s // cq, s // ck
-    scale = dh**-0.5
+    scale = dh**-0.5 if scale is None else scale
     qg = q.reshape(b, nq, cq, kvh, rep, dh)
     kg = k.reshape(b, nk, ck, kvh, dh)
-    vg = v.reshape(b, nk, ck, kvh, dh)
-    og = o.reshape(b, nq, cq, kvh, rep, dh)
-    dog = do.reshape(b, nq, cq, kvh, rep, dh)
+    vg = v.reshape(b, nk, ck, kvh, d_v)
+    og = o.reshape(b, nq, cq, kvh, rep, d_v)
+    dog = do.reshape(b, nq, cq, kvh, rep, d_v)
     # delta[iq] = rowsum(do * o): (B, nq, KV, rep, Cq)
     delta = jnp.einsum("bnqkrd,bnqkrd->bnkrq", dog.astype(jnp.float32),
                        og.astype(jnp.float32))
@@ -204,26 +250,28 @@ def _flash_bwd(res, do, window, cq, ck):
                                          qb.astype(jnp.float32))
             return (dk_acc, dv_acc), None
 
-        z = jnp.zeros((b, ck, kvh, dh), jnp.float32)
         (dkb, dvb), _ = jax.lax.scan(
-            inner, (z, z), jnp.arange(nq, dtype=jnp.int32)
+            inner, (jnp.zeros((b, ck, kvh, dh), jnp.float32),
+                    jnp.zeros((b, ck, kvh, d_v), jnp.float32)),
+            jnp.arange(nq, dtype=jnp.int32)
         )
         return None, (dkb, dvb)
 
     _, (dks, dvs) = jax.lax.scan(dkv_block, None, jnp.arange(nk, dtype=jnp.int32))
     dk = jnp.moveaxis(dks, 0, 1).reshape(b, s, kvh, dh).astype(k.dtype)
-    dv = jnp.moveaxis(dvs, 0, 1).reshape(b, s, kvh, dh).astype(v.dtype)
+    dv = jnp.moveaxis(dvs, 0, 1).reshape(b, s, kvh, d_v).astype(v.dtype)
     return dq, dk, dv
 
 
-def dense_attention(q, k, v, *, window=None):
-    """Reference O(S^2)-memory attention (tests / tiny shapes)."""
+def dense_attention(q, k, v, *, window=None, scale=None):
+    """Reference O(S^2)-memory attention (tests / tiny shapes).  ``v`` may
+    be narrower than ``q``/``k``; ``scale`` None = dh ** -0.5."""
     b, s, h, dh = q.shape
     kvh = k.shape[2]
     rep = h // kvh
     qg = q.reshape(b, s, kvh, rep, dh)
     s_ = jnp.einsum("bqkrd,bckd->bkrqc", qg, k, preferred_element_type=jnp.float32)
-    s_ = s_ * (dh**-0.5)
+    s_ = s_ * (dh**-0.5 if scale is None else scale)
     i = jnp.arange(s)[:, None]
     j = jnp.arange(s)[None, :]
     m = j <= i
@@ -232,7 +280,7 @@ def dense_attention(q, k, v, *, window=None):
     s_ = jnp.where(m[None, None, None], s_, NEG)
     p = jax.nn.softmax(s_, axis=-1)
     o = jnp.einsum("bkrqc,bckd->bqkrd", p.astype(v.dtype), v)
-    return o.reshape(b, s, h, dh)
+    return o.reshape(b, s, h, v.shape[-1])
 
 
 def verify_attention(
@@ -335,6 +383,34 @@ def decode_attention(
     o = jnp.einsum("bkrc,bckd->bkrd", (e_c / den).astype(v_cache.dtype), v_cache)
     o = o + (e_s / den).astype(v_new.dtype) * v_new[:, 0][:, :, None, :]
     return o.reshape(b, 1, h, dh)
+
+
+def latent_decode_attention(
+    q_lat: jnp.ndarray,  # (B, H, R) — queries with kv_b's key part absorbed
+    q_pe: jnp.ndarray,  # (B, H, P) — RoPE'd rope part of the queries
+    c_cache: jnp.ndarray,  # (B, Sc, R) — normalised latent rows
+    pe_cache: jnp.ndarray,  # (B, Sc, P) — RoPE'd rope key rows
+    kv_pos: jnp.ndarray,  # (B, Sc) absolute positions, -1 = empty slot
+    cur_pos: jnp.ndarray,  # (B,) position of the current token
+    scale: float,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """Latent-attention decode (MLA with absorbed weights): every query head
+    scores its (R + P)-wide ``[q_lat | q_pe]`` against the cached
+    ``[c | k_pe]`` rows, one key shared by all heads, and takes the
+    probability-weighted latent rows.  Returns (B, H, R) float32, still to
+    be multiplied by kv_b's value part."""
+    s_ = (jnp.einsum("bhr,bsr->bhs", q_lat, c_cache,
+                     preferred_element_type=jnp.float32)
+          + jnp.einsum("bhp,bsp->bhs", q_pe, pe_cache,
+                       preferred_element_type=jnp.float32)) * scale
+    ok = (kv_pos >= 0) & (kv_pos <= cur_pos[:, None])
+    if window is not None:
+        ok &= (cur_pos[:, None] - kv_pos) < window
+    s_ = jnp.where(ok[:, None], s_, NEG)
+    p = jax.nn.softmax(s_, axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", p.astype(c_cache.dtype), c_cache,
+                      preferred_element_type=jnp.float32)
 
 
 # --------------------------------------------------------------------------

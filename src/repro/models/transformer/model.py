@@ -7,6 +7,11 @@ up to 131k), ring-buffer KV cache for long-context decode.
 Layers are stacked on a leading L axis and driven by `lax.scan` (+ optional
 `jax.checkpoint`), so HLO size and compile time are depth-independent — a
 hard requirement for the 62-layer/33B dry-run on this container.
+
+A config with ``mla`` set (DeepSeek-V2) takes the latent-attention path of
+the section at the end: expanded attention for training and prefill,
+absorbed attention over a latent cache for decode, leading dense layers in
+a segment of their own, and dropless held-share MoE layers.
 """
 from __future__ import annotations
 
@@ -20,7 +25,9 @@ import jax.numpy as jnp
 from repro.distributed.constraints import shard_hint
 from repro.models.transformer import attention as attn
 from repro.models.transformer.config import TransformerConfig
-from repro.models.transformer.moe import init_moe_params, moe_ffn
+from repro.models.transformer.moe import (
+    init_moe_params, moe_ffn, moe_held, swiglu,
+)
 
 
 def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -117,6 +124,8 @@ def _layer_train(x, p, cfg: TransformerConfig, positions):
 # --------------------------------------------------------------------------
 def backbone(params, tokens: jnp.ndarray, cfg: TransformerConfig) -> tuple:
     """tokens (B, S) -> (hidden (B, S, D), aux_loss)."""
+    if cfg.mla is not None:
+        return _mla_backbone(params, tokens, cfg)
     x = params["embed"][tokens]
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
 
@@ -192,16 +201,34 @@ def lm_loss(params, tokens, loss_mask, cfg: TransformerConfig, aux_weight=0.01):
 # --------------------------------------------------------------------------
 @dataclasses.dataclass
 class KVCache:
+    """The contiguous arena: per layer, slot and position one cached row.
+
+    Under latent attention (``cfg.mla``) the same fields hold the latent
+    cache, one row that every head shares and so no head axis: ``k``
+    (L, B, Sc, kv_rank) is the normalised latent and ``v``
+    (L, B, Sc, rope_dim) the rotated key, with Sc rounded up to a multiple
+    of 8 (``_mla_rows``).  Everything that moves rows between slots
+    (prefill, the engine's arena merge) is elementwise over (L, B, ...)
+    leaves and so carries either layout unchanged.  ``routed`` and
+    ``max_load`` are the held-share MoE's routing counters, accumulated on
+    the device by every decode step (None without such layers).
+    """
+
     k: jnp.ndarray  # (L, B, Sc, KV, dh) — int8 when quantized
     v: jnp.ndarray  # (L, B, Sc, KV, dh)
     pos: jnp.ndarray  # (B, Sc) absolute position per slot, -1 empty
     cursor: jnp.ndarray  # (B,) next absolute position to write
     k_scale: object = None  # (L, B, Sc, KV) bf16 absmax scales (int8 mode)
     v_scale: object = None
+    # token-slots routed to each held expert, over every MoE layer and
+    # decode row (live or not) since the arena was made: (n_held,) int32
+    routed: object = None
+    max_load: object = None  # () int32 largest one-layer load of a step
 
 
 jax.tree_util.register_dataclass(
-    KVCache, data_fields=["k", "v", "pos", "cursor", "k_scale", "v_scale"],
+    KVCache, data_fields=["k", "v", "pos", "cursor", "k_scale", "v_scale",
+                          "routed", "max_load"],
     meta_fields=[],
 )
 
@@ -216,6 +243,8 @@ def _quant_rows(x: jnp.ndarray):
 
 def init_cache(cfg: TransformerConfig, batch: int, cache_len: int) -> KVCache:
     dtype = jnp.dtype(cfg.dtype)
+    if cfg.mla is not None:
+        return _mla_init_cache(cfg, batch, cache_len)
     shape = (cfg.n_layers, batch, cache_len, cfg.n_kv_heads, cfg.d_head)
     if cfg.kv_quant:
         return KVCache(
@@ -239,6 +268,8 @@ def prefill(params, tokens, true_len, cfg: TransformerConfig, cache_len: int):
 
     tokens (B, S) left-aligned, padded; true_len (B,).  Requires S <= cache_len.
     """
+    if cfg.mla is not None:
+        return _mla_prefill(params, tokens, true_len, cfg, cache_len)
     b, s = tokens.shape
     assert s <= cache_len
     x = params["embed"][tokens]
@@ -305,6 +336,8 @@ def prefill(params, tokens, true_len, cfg: TransformerConfig, cache_len: int):
 
 def decode_step(params, cache: KVCache, token, cfg: TransformerConfig):
     """One decode step.  token (B,) int32 -> (logits (B, V), new cache)."""
+    if cfg.mla is not None:
+        return _mla_decode_step(params, cache, token, cfg)
     b = token.shape[0]
     sc = cache.k.shape[2]
     cur = cache.cursor  # (B,) position of the token being processed
@@ -400,6 +433,7 @@ def verify_window(params, cache: KVCache, tokens, cfg: TransformerConfig):
     later query position until the cursor catches up, so the `<=` mask hides
     them, and the next window overwrites them before any attention runs.
     """
+    _contiguous_only(cfg, "speculative verification")
     b, w = tokens.shape
     sc = cache.k.shape[2]
     cur = cache.cursor  # (B,)
@@ -577,6 +611,7 @@ jax.tree_util.register_dataclass(
 
 def init_paged_cache(cfg: TransformerConfig, batch: int, cache_len: int,
                      block_size: int, pool_blocks: int) -> PagedKVCache:
+    _contiguous_only(cfg, "the paged KV arena")
     if cache_len % block_size != 0:
         raise ValueError(
             f"block_size={block_size} must divide cache_len={cache_len}"
@@ -998,3 +1033,239 @@ def paged_verify_step(params, cache: PagedKVCache, tokens, room, live,
     accepted, cur_tok = _accept_prefix(greedy, tokens, room, w, eos_id)
     cache = dataclasses.replace(cache, cursor=cache.cursor + accepted)
     return greedy, accepted, cur_tok, cache
+
+
+# --------------------------------------------------------------------------
+# latent attention (DeepSeek-V2): MLA, leading dense layers, held-share MoE
+# --------------------------------------------------------------------------
+# Parameter layout: ``params["layers"]`` stacks the layers after the leading
+# dense ones (MoE when ``cfg.moe`` is set), ``params["dense"]`` the
+# ``cfg.moe.dense_layers`` leading ones; each layer has ``ln1``, ``ln2``,
+# ``wq`` (D, H * (nope + rope)), ``wkv_a`` (D, rank + rope), ``kv_norm``
+# (rank,), ``wkv_b`` (rank, H * (nope + v)), ``wo`` (H * v, D), and either
+# ``w1``/``w3``/``w2`` (the dense FFN) or ``moe`` (see moe.moe_held).
+def _contiguous_only(cfg: TransformerConfig, what: str) -> None:
+    if cfg.mla is not None:
+        raise ValueError(
+            f"{what} does not carry a latent KV cache; config {cfg.name!r} "
+            f"uses latent attention (MLA) and is served on the contiguous "
+            f"arena with one-token decode")
+
+
+def _mla_segments(params, cfg: TransformerConfig) -> list:
+    """(stacked layers, dense FFN?) in depth order."""
+    n_dense = cfg.moe.dense_layers if cfg.moe is not None else 0
+    segs = [(params["dense"], True)] if n_dense else []
+    return segs + [(params["layers"], cfg.moe is None)]
+
+
+def _mla_rope(x, positions, cfg: TransformerConfig):
+    m = cfg.mla
+    inv = attn.yarn_inv_freq(m.rope_dim, cfg.rope_theta, m.yarn_factor,
+                             m.yarn_original_max, m.yarn_beta_fast,
+                             m.yarn_beta_slow)
+    return attn.rope_freqs(x, positions, inv, m.rope_mscale)
+
+
+def _mla_proj(p, xn, positions, cfg: TransformerConfig):
+    """(q_nope (B, S, H, nope), RoPE'd q_pe (B, S, H, rope), normalised
+    latent c (B, S, rank), RoPE'd shared key k_pe (B, S, rope))."""
+    m = cfg.mla
+    b, s, _ = xn.shape
+    q = (xn @ p["wq"]).reshape(b, s, cfg.n_heads, m.qk_dim)
+    kv = xn @ p["wkv_a"]
+    c = rms_norm(kv[..., :m.kv_rank], p["kv_norm"], cfg.norm_eps)
+    k_pe = _mla_rope(kv[..., None, m.kv_rank:], positions, cfg)[:, :, 0]
+    q_pe = _mla_rope(q[..., m.nope_dim:], positions, cfg)
+    return q[..., :m.nope_dim], q_pe, c, k_pe
+
+
+def _mla_ffn(p, xn, cfg: TransformerConfig, dense: bool):
+    """xn (T, D) -> (y (T, D), aux, held-expert load or None)."""
+    if dense:
+        return swiglu(xn, p["w1"], p["w3"], p["w2"]), jnp.zeros(
+            (), jnp.float32), None
+    return moe_held(p["moe"], xn, cfg.moe)
+
+
+def _mla_layer(x, p, cfg: TransformerConfig, positions, dense: bool):
+    """A whole-sequence layer with expanded attention: kv_b projects every
+    position's latent to per-head keys and values.  Returns (x, aux,
+    (c, k_pe)), the latter the rows the latent cache keeps."""
+    m = cfg.mla
+    b, s, _ = x.shape
+    h = cfg.n_heads
+    xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+    with jax.named_scope("mla_attention"):
+        q_nope, q_pe, c, k_pe = _mla_proj(p, xn, positions, cfg)
+        kv = (c @ p["wkv_b"]).reshape(b, s, h, m.nope_dim + m.v_dim)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :m.nope_dim],
+             jnp.broadcast_to(k_pe[:, :, None], (b, s, h, m.rope_dim))],
+            axis=-1)
+        v = kv[..., m.nope_dim:]
+        if s <= max(cfg.q_chunk, 256):
+            o = attn.dense_attention(q, k, v, window=cfg.sliding_window,
+                                     scale=m.softmax_scale)
+        else:
+            o = attn.chunked_attention(
+                q, k, v, window=cfg.sliding_window, q_chunk=cfg.q_chunk,
+                kv_chunk=cfg.kv_chunk, scale=m.softmax_scale)
+        x = x + (o.reshape(b, s, h * m.v_dim) @ p["wo"]).astype(x.dtype)
+    xn = rms_norm(x, p["ln2"], cfg.norm_eps)
+    y, aux, _ = _mla_ffn(p, xn.reshape(b * s, -1), cfg, dense)
+    return x + y.reshape(b, s, -1).astype(x.dtype), aux, (c, k_pe)
+
+
+def _mla_backbone(params, tokens, cfg: TransformerConfig):
+    x = params["embed"][tokens]
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+    aux = jnp.zeros((), jnp.float32)
+    for stack, dense in _mla_segments(params, cfg):
+        def body(carry, p, dense=dense):
+            x, aux = carry
+            x, a, _ = _mla_layer(x, p, cfg, positions, dense)
+            return (x, aux + a), None
+
+        fn = jax.checkpoint(body) if cfg.remat else body
+        (x, aux), _ = jax.lax.scan(fn, (x, aux), stack)
+    return rms_norm(x, params["ln_f"], cfg.norm_eps), aux
+
+
+def _mla_rows(cache_len: int) -> int:
+    """Rows of a latent arena of ``cache_len`` positions: rounded up to the
+    TPU's sublane tile of 8.  Otherwise the device tiles the arena over
+    (slot, rank) to avoid padding, and every layer's rows are copied to
+    another layout before they are attended.  The rows past ``cache_len``
+    stay empty (``pos == -1``): the engine retires a slot at
+    ``cache_len``."""
+    return -(-cache_len // 8) * 8
+
+
+def _mla_init_cache(cfg: TransformerConfig, batch: int, cache_len: int):
+    if cfg.kv_quant:
+        raise ValueError("the latent cache has no int8 mode")
+    m = cfg.mla
+    dtype = jnp.dtype(cfg.dtype)
+    rows = _mla_rows(cache_len)
+    lead = (cfg.n_layers, batch, rows)
+    return KVCache(
+        k=jnp.zeros(lead + (m.kv_rank,), dtype),
+        v=jnp.zeros(lead + (m.rope_dim,), dtype),
+        pos=jnp.full((batch, rows), -1, jnp.int32),
+        cursor=jnp.zeros((batch,), jnp.int32),
+        **_route_counters(cfg),
+    )
+
+
+def _route_counters(cfg: TransformerConfig) -> dict:
+    if cfg.moe is None:
+        return {}
+    return {"routed": jnp.zeros((cfg.moe.held,), jnp.int32),
+            "max_load": jnp.zeros((), jnp.int32)}
+
+
+def _mla_prefill(params, tokens, true_len, cfg: TransformerConfig,
+                 cache_len: int):
+    b, s = tokens.shape
+    assert s <= cache_len
+    x = params["embed"][tokens]
+    positions = jnp.arange(s, dtype=jnp.int32)[None, :]
+    cs, pes = [], []
+    for stack, dense in _mla_segments(params, cfg):
+        def body(x, p, dense=dense):
+            x, _, rows = _mla_layer(x, p, cfg, positions, dense)
+            return x, rows
+
+        x, (c, pe) = jax.lax.scan(body, x, stack)
+        cs.append(c)
+        pes.append(pe)
+    rows = _mla_rows(cache_len)
+    pad = ((0, 0), (0, 0), (0, rows - s), (0, 0))
+    kc = jnp.pad(jnp.concatenate(cs), pad)
+    pc = jnp.pad(jnp.concatenate(pes), pad)
+    slot_pos = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    pos = jnp.where(slot_pos < true_len[:, None], slot_pos, -1)
+    cache = KVCache(k=kc, v=pc, pos=pos, cursor=true_len.astype(jnp.int32))
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    last = jnp.take_along_axis(
+        x, jnp.maximum(true_len - 1, 0)[:, None, None].astype(jnp.int32), axis=1
+    )
+    logits = last.astype(jnp.float32) @ params["head"].astype(jnp.float32)
+    return logits[:, 0], cache
+
+
+def _mla_absorbed(p, q_nope, q_pe, c_rows, pe_rows, pos, cur,
+                  cfg: TransformerConfig):
+    """Decode attention with kv_b absorbed: its key part folds into the
+    query, its value part into the output, so the step attends over the
+    cached latent rows themselves.  q_nope (B, H, nope), q_pe (B, H, rope),
+    c_rows (B, Sc, rank), pe_rows (B, Sc, rope) -> (B, H * v)."""
+    m = cfg.mla
+    b, h = q_nope.shape[:2]
+    wkv_b = p["wkv_b"].reshape(m.kv_rank, h, m.nope_dim + m.v_dim)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :m.nope_dim],
+                       preferred_element_type=jnp.float32)
+    o_lat = attn.latent_decode_attention(
+        q_lat.astype(c_rows.dtype), q_pe, c_rows, pe_rows, pos, cur,
+        m.softmax_scale, cfg.sliding_window)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(c_rows.dtype),
+                   wkv_b[..., m.nope_dim:], preferred_element_type=jnp.float32)
+    return o.astype(c_rows.dtype).reshape(b, h * m.v_dim)
+
+
+def _mla_decode_step(params, cache: KVCache, token, cfg: TransformerConfig):
+    """One decode step over the latent cache.  The arena rides in the layer
+    loop's carry, so each layer writes only its B new rows in place before
+    it attends, and the leading dense layers need no second arena to be
+    joined to."""
+    b = token.shape[0]
+    sc = cache.k.shape[2]
+    cur = cache.cursor
+    slot = cur % sc
+    bidx = jnp.arange(b)
+    pos = jnp.where(jnp.arange(sc, dtype=jnp.int32)[None, :] == slot[:, None],
+                    cur[:, None], cache.pos)
+    x = params["embed"][token][:, None]  # (B, 1, D)
+
+    def layer(carry, p, i, dense):
+        x, kc, pc, routed, top = carry
+        xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+        with jax.named_scope("mla_attention"):
+            q_nope, q_pe, c, k_pe = _mla_proj(p, xn, cur[:, None], cfg)
+            kc = kc.at[i, bidx, slot].set(c[:, 0])
+            pc = pc.at[i, bidx, slot].set(k_pe[:, 0])
+            o = _mla_absorbed(p, q_nope[:, 0], q_pe[:, 0], kc[i], pc[i], pos,
+                              cur, cfg)
+            x = x + (o[:, None] @ p["wo"]).astype(x.dtype)
+        xn = rms_norm(x, p["ln2"], cfg.norm_eps)
+        y, _, load = _mla_ffn(p, xn.reshape(b, -1), cfg, dense)
+        if load is not None:
+            routed = routed + load
+            top = jnp.maximum(top, jnp.max(load))
+        return (x + y[:, None].astype(x.dtype), kc, pc, routed, top)
+
+    held = cfg.moe.held if cfg.moe is not None else 0
+    routed = cache.routed if cache.routed is not None else jnp.zeros(
+        (held,), jnp.int32)
+    carry = (x, cache.k, cache.v, routed, jnp.zeros((), jnp.int32))
+    first = 0
+    for stack, dense in _mla_segments(params, cfg):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        idx = jnp.arange(first, first + n, dtype=jnp.int32)
+
+        def body(carry, xs, dense=dense):
+            return layer(carry, xs[0], xs[1], dense), None
+
+        carry, _ = jax.lax.scan(body, carry, (stack, idx))
+        first += n
+    x, kc, pc, routed, top = carry
+    counters = {}
+    if cfg.moe is not None:
+        prev = cache.max_load if cache.max_load is not None else top
+        counters = {"routed": routed, "max_load": jnp.maximum(prev, top)}
+    new_cache = KVCache(k=kc, v=pc, pos=pos, cursor=cur + 1, **counters)
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    logits = x[:, 0].astype(jnp.float32) @ params["head"].astype(jnp.float32)
+    return logits, new_cache

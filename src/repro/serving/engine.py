@@ -47,6 +47,7 @@ import numpy as np
 from repro import tracing
 from repro.models.transformer import model as tm
 from repro.models.transformer.config import TransformerConfig
+from repro.models.transformer.moe import token_chunks
 from repro.serving.drafter import draft_tokens
 
 
@@ -207,6 +208,8 @@ def _merge_admitted(arena: tm.KVCache, new: tm.KVCache, cur_tok, first,
         cursor=mix_b0(arena.cursor, new.cursor),
         k_scale=mix_b1(arena.k_scale, new.k_scale),
         v_scale=mix_b1(arena.v_scale, new.v_scale),
+        routed=arena.routed,
+        max_load=arena.max_load,
     )
     return cache, jnp.where(newly, first[rows], cur_tok)
 
@@ -346,6 +349,13 @@ class ServeEngine:
         self.live = np.zeros(slots, bool)
         self.paged_kv = env_flag("RGL_PAGED_KV") if paged_kv is None \
             else bool(paged_kv)
+        if cfg.mla is not None and (self.paged_kv or self.spec_decode):
+            raise ValueError(
+                f"config {cfg.name!r} uses latent attention (MLA), whose "
+                f"latent cache lives on the contiguous arena with one-token "
+                f"decode only; the paged arena and speculative decoding do "
+                f"not carry it (paged_kv={self.paged_kv}, "
+                f"spec_decode={self.spec_decode})")
         # prefix sharing is a paged-arena feature: on a contiguous arena the
         # flag is inert (admission behaves exactly as before), so the
         # contiguous cells of the CI matrix double as the fallback parity leg
@@ -796,8 +806,13 @@ class ServeEngine:
                 max(len(reqs[j].prompt_ids) for j, _ in fresh_pairs),
                 self.cache_len,
             )
-            with tracing.span("prefill", rows=len(fresh_pairs),
-                              bucket=bucket):
+            args = {"rows": len(fresh_pairs), "bucket": bucket}
+            moe = self.cfg.moe
+            if moe is not None and moe.capacity_factor is None:
+                # the held experts' token chunks in this prefill (0 = one
+                # dense pass; moe.token_chunks)
+                args["expert_chunks"] = token_chunks(self.slots * bucket)
+            with tracing.span("prefill", **args):
                 self._prefill(reqs, fresh_pairs, bucket, first_by_slot)
         # -- shared population: alias donor blocks, no prefill dispatch
         if plans:
@@ -1087,6 +1102,12 @@ class ServeEngine:
             "prefill_rows": self.prefill_rows,
             "admit_seconds": self.admit_seconds,
         }
+        if getattr(self.cache, "routed", None) is not None:
+            # the held experts' routing counters: one device read, here only
+            stats.update(
+                routed_per_expert=np.asarray(self.cache.routed).tolist(),
+                max_expert_load=int(self.cache.max_load),
+            )
         if self.paged_kv:
             stats.update(
                 block_size=self.block_size,

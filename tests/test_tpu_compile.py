@@ -9,7 +9,10 @@ and every compile lives in this one file so one worker owns libtpu.
 """
 import contextlib
 import functools
+import json
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +123,43 @@ def test_prefill_compiles_at_published_width(lm, sds):
         functools.partial(_prefill_batch, cfg=cfg, cache_len=4096), params,
         sds((4, 512), jnp.int32), sds((4,), jnp.int32))
     assert _fits(compiled) > 8 * 2**30
+
+
+@pytest.fixture(scope="module")
+def dsv2(sds):
+    """DeepSeek-V2-Lite as the benchmark serves it: published widths, all
+    27 layers, 8 of 64 experts held (``bench/configs``)."""
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench import models
+
+    conf = json.loads((root / "bench" / "configs" /
+                       "dsv2-lite-e8-arxiv.json").read_text())
+    fam = models.family(conf["model"])
+    cfg = fam.program_config(conf["model"], conf["name"])
+    shapes = jax.eval_shape(lambda: fam.make_params(conf["model"], 0))
+    return cfg, jax.tree.map(lambda x: sds(x.shape, x.dtype), shapes)
+
+
+def test_latent_attention_serving_fits_one_chip(dsv2, sds):
+    """The 64-slot decode step and a 64 x 512 prefill wave, with the
+    latent arena (2.07 GB) live beside the prefill, under 15 GB."""
+    cfg, params = dsv2
+    slots, cache_len = 64, 1025
+    cache = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: tm.init_cache(cfg, slots, cache_len)))
+    arena = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    _fits(_compile(functools.partial(tm.serve_step, cfg=cfg), params, cache,
+                   sds((slots,), jnp.int32)))
+    pre = _compile(
+        functools.partial(_prefill_batch, cfg=cfg, cache_len=cache_len),
+        params, sds((slots, 512), jnp.int32), sds((slots,), jnp.int32))
+    m = pre.memory_analysis()
+    assert m.argument_size_in_bytes > 6 * 10**9  # the bf16 weights
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes + arena) < 15 * 10**9
 
 
 def test_compact_bfs_csr_gather_compiles(sds):
