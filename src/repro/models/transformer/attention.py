@@ -338,11 +338,9 @@ def decode_attention(
     kv_pos: jnp.ndarray,  # (B, Sc) absolute positions, -1 = empty slot
     cur_pos: jnp.ndarray,  # (B,) position of the current token
     window: Optional[int] = None,
-    k_new: Optional[jnp.ndarray] = None,  # (B, 1, KV, dh) — current token's
-    v_new: Optional[jnp.ndarray] = None,  # k/v, appended WITHOUT writing the
     k_scale: Optional[jnp.ndarray] = None,  # (B, Sc, KV) int8-mode absmax
     v_scale: Optional[jnp.ndarray] = None,  # scales (dequant fused into dots)
-) -> jnp.ndarray:  # cache (avoids per-layer full-cache copies; §Perf decode)
+) -> jnp.ndarray:
     b, _, h, dh = q.shape
     kvh = k_cache.shape[2]
     rep = h // kvh
@@ -353,35 +351,20 @@ def decode_attention(
     ) * (dh**-0.5)  # (B, KV, rep, Sc)
     if k_scale is not None:  # int8 cache: fold dequant scale into the scores
         s_ = s_ * jnp.transpose(k_scale, (0, 2, 1)).astype(jnp.float32)[:, :, None]
-    # strict `<` : if k_new is given the current position is handled by the
-    # appended self term, and the ring slot being overwritten is stale.
-    lim_ok = kv_pos < cur_pos[:, None] if k_new is not None else (
-        kv_pos <= cur_pos[:, None]
-    )
-    ok = (kv_pos >= 0) & lim_ok
+    causal = kv_pos <= cur_pos[:, None]
+    ok = (kv_pos >= 0) & causal
     if window is not None:
         ok &= (cur_pos[:, None] - kv_pos) < window
     s_ = jnp.where(ok[:, None, None], s_, NEG)
-    if k_new is None:
-        p = jax.nn.softmax(s_, axis=-1)
-        if v_scale is not None:  # fold dequant into the probabilities
-            p = p * jnp.transpose(v_scale, (0, 2, 1)).astype(p.dtype)[:, :, None]
-            o = jnp.einsum(
-                "bkrc,bckd->bkrd", p, v_cache.astype(p.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            return o.astype(qg.dtype).reshape(b, 1, h, dh)
-        o = jnp.einsum("bkrc,bckd->bkrd", p.astype(v_cache.dtype), v_cache)
-        return o.reshape(b, 1, h, dh)
-    s_self = jnp.einsum(
-        "bkrd,bkd->bkr", qg, k_new[:, 0], preferred_element_type=jnp.float32
-    )[..., None] * (dh**-0.5)  # (B, KV, rep, 1)
-    m = jnp.maximum(jnp.max(s_, axis=-1, keepdims=True), s_self)
-    e_c = jnp.exp(s_ - m)
-    e_s = jnp.exp(s_self - m)
-    den = jnp.sum(e_c, axis=-1, keepdims=True) + e_s
-    o = jnp.einsum("bkrc,bckd->bkrd", (e_c / den).astype(v_cache.dtype), v_cache)
-    o = o + (e_s / den).astype(v_new.dtype) * v_new[:, 0][:, :, None, :]
+    p = jax.nn.softmax(s_, axis=-1)
+    if v_scale is not None:  # fold dequant into the probabilities
+        p = p * jnp.transpose(v_scale, (0, 2, 1)).astype(p.dtype)[:, :, None]
+        o = jnp.einsum(
+            "bkrc,bckd->bkrd", p, v_cache.astype(p.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        return o.astype(qg.dtype).reshape(b, 1, h, dh)
+    o = jnp.einsum("bkrc,bckd->bkrd", p.astype(v_cache.dtype), v_cache)
     return o.reshape(b, 1, h, dh)
 
 

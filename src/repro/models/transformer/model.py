@@ -342,36 +342,31 @@ def decode_step(params, cache: KVCache, token, cfg: TransformerConfig):
     sc = cache.k.shape[2]
     cur = cache.cursor  # (B,) position of the token being processed
     slot = cur % sc
-    x = params["embed"][token][:, None]  # (B, 1, D)
     bidx = jnp.arange(b)
-    # Masked-broadcast cache update (elementwise => shards cleanly; a scatter
-    # into the sequence-sharded cache made GSPMD gather the whole cache).
-    # An append-attention variant with a single top-level scatter was tried
-    # and REFUTED on memory (§Perf decode iterations: 28.8 -> 37.9 GiB —
-    # scan xs double-buffering dominates); decode_attention(k_new=...) is
-    # kept for serving-engine use.
-    slot_mask = jnp.arange(sc, dtype=jnp.int32)[None, :] == slot[:, None]  # (B, Sc)
+    pos = jnp.where(jnp.arange(sc, dtype=jnp.int32)[None, :] == slot[:, None],
+                    cur[:, None], cache.pos)
+    x = params["embed"][token][:, None]  # (B, 1, D)
     quant = cfg.kv_quant
 
-    def body(x, inputs):
-        p, kc, vc, ks, vs = inputs
+    # The arena rides in the layer loop's carry: each layer writes its B new
+    # rows (and their scales) in place at (layer, b, cur % Sc), then attends.
+    def body(carry, xs):
+        x, kc, vc, ks, vs = carry
+        p, i = xs
         xn = rms_norm(x, p["ln1"], cfg.norm_eps)
         q, k, v = _attn_proj(p, xn, cfg)
         q = attn.rope(q, cur[:, None], cfg.rope_theta)
         k = attn.rope(k, cur[:, None], cfg.rope_theta)
         if quant:
-            kq, ksc = _quant_rows(k)
-            vq, vsc = _quant_rows(v)
-            kc = jnp.where(slot_mask[:, :, None, None], kq[:, 0][:, None], kc)
-            vc = jnp.where(slot_mask[:, :, None, None], vq[:, 0][:, None], vc)
-            ks = jnp.where(slot_mask[:, :, None], ksc[:, 0][:, None], ks)
-            vs = jnp.where(slot_mask[:, :, None], vsc[:, 0][:, None], vs)
-        else:
-            kc = jnp.where(slot_mask[:, :, None, None], k[:, 0][:, None], kc)
-            vc = jnp.where(slot_mask[:, :, None, None], v[:, 0][:, None], vc)
-        pos = jnp.where(slot_mask, cur[:, None], cache.pos)
+            k, ksc = _quant_rows(k)
+            v, vsc = _quant_rows(v)
+            ks = ks.at[i, bidx, slot].set(ksc[:, 0])
+            vs = vs.at[i, bidx, slot].set(vsc[:, 0])
+        kc = kc.at[i, bidx, slot].set(k[:, 0])
+        vc = vc.at[i, bidx, slot].set(v[:, 0])
         o = attn.decode_attention(
-            q, kc, vc, pos, cur, cfg.sliding_window, k_scale=ks, v_scale=vs
+            q, kc[i], vc[i], pos, cur, cfg.sliding_window,
+            k_scale=ks[i] if quant else None, v_scale=vs[i] if quant else None,
         )
         x = x + (o.reshape(b, 1, -1) @ p["wo"]).astype(x.dtype)
         xn = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -380,32 +375,29 @@ def decode_step(params, cache: KVCache, token, cfg: TransformerConfig):
         else:
             y, _ = moe_ffn(p["moe"], xn.reshape(b, -1), cfg.moe)
             y = y[:, None]
-        return x + y.astype(x.dtype), (kc, vc, ks, vs)
+        return (x + y.astype(x.dtype), kc, vc, ks, vs), None
 
-    xs = (params["layers"], cache.k, cache.v, cache.k_scale, cache.v_scale)
+    carry = (x, cache.k, cache.v, cache.k_scale, cache.v_scale)
     if cfg.scan_layers:
-        x, (kc, vc, ks, vs) = jax.lax.scan(body, x, xs)
+        idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        carry, _ = jax.lax.scan(body, carry, (params["layers"], idx))
     else:  # unrolled (cost-analysis variants)
-        outs = []
         for i in range(cfg.n_layers):
-            sl = jax.tree.map(lambda a: a[i], xs)
-            x, o_i = body(x, sl)
-            outs.append(o_i)
-        cols = list(zip(*outs))
-        kc, vc = jnp.stack(cols[0]), jnp.stack(cols[1])
-        ks = jnp.stack(cols[2]) if quant else None
-        vs = jnp.stack(cols[3]) if quant else None
-    new_pos = jnp.where(slot_mask, cur[:, None], cache.pos)
-    new_cache = KVCache(k=kc, v=vc, pos=new_pos, cursor=cur + 1,
+            p = jax.tree.map(lambda a: a[i], params["layers"])
+            carry, _ = body(carry, (p, i))
+    x, kc, vc, ks, vs = carry
+    new_cache = KVCache(k=kc, v=vc, pos=pos, cursor=cur + 1,
                         k_scale=ks, v_scale=vs)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = x[:, 0].astype(jnp.float32) @ params["head"].astype(jnp.float32)
     return logits, new_cache
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("cache",))
 def serve_step(params, cache: KVCache, token, cfg: TransformerConfig):
-    """Greedy decode step — the unit the decode/long dry-run shapes lower."""
+    """Greedy decode step.  The cache is donated: the step writes its new
+    rows into the caller's arena, which the caller must not read again."""
     logits, cache = decode_step(params, cache, token, cfg)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 

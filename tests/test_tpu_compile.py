@@ -125,21 +125,46 @@ def test_prefill_compiles_at_published_width(lm, sds):
     assert _fits(compiled) > 8 * 2**30
 
 
-@pytest.fixture(scope="module")
-def dsv2(sds):
-    """DeepSeek-V2-Lite as the benchmark serves it: published widths, all
-    27 layers, 8 of 64 experts held (``bench/configs``)."""
+def _bench_lm(sds, name: str):
+    """(cfg, params) of a benchmark configuration (``bench/configs``) as
+    the benchmark serves it, the params as shapes on one described chip."""
     root = Path(__file__).resolve().parents[1]
     if str(root) not in sys.path:
         sys.path.insert(0, str(root))
     from bench import models
 
-    conf = json.loads((root / "bench" / "configs" /
-                       "dsv2-lite-e8-arxiv.json").read_text())
+    conf = json.loads((root / "bench" / "configs" / f"{name}.json").read_text())
     fam = models.family(conf["model"])
     cfg = fam.program_config(conf["model"], conf["name"])
     shapes = jax.eval_shape(lambda: fam.make_params(conf["model"], 0))
     return cfg, jax.tree.map(lambda x: sds(x.shape, x.dtype), shapes)
+
+
+@pytest.fixture(scope="module")
+def dsv2(sds):
+    """DeepSeek-V2-Lite as the benchmark serves it: published widths, all
+    27 layers, 8 of 64 experts held."""
+    return _bench_lm(sds, "dsv2-lite-e8-arxiv")
+
+
+@pytest.mark.parametrize("name,slots", [("dsk-7b-l15-arxiv", 8),
+                                        ("dsv2-lite-e8-arxiv", 64)])
+def test_serve_step_updates_the_arena_in_place(sds, name, slots):
+    """The decode step as the benchmark runs it, all its layers, 1,025
+    positions a slot: the donated arena is the output's (aliased, not
+    copied), and the step's temporaries stay below one arena."""
+    cfg, params = _bench_lm(sds, name)
+    cache = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: tm.init_cache(cfg, slots, 1025)))
+    arena = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert arena > 2 * 10**9
+    with _no_persistent_cache():  # tm.serve_step's own jit, with donation
+        compiled = tm.serve_step.lower(params, cache, sds((slots,), jnp.int32),
+                                       cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= arena, (m.alias_size_in_bytes, arena)
+    assert m.temp_size_in_bytes < arena, (m.temp_size_in_bytes, arena)
 
 
 def test_latent_attention_serving_fits_one_chip(dsv2, sds):

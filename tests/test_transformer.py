@@ -1,12 +1,19 @@
 """LM stack: attention equivalence, flash VJP, serve-path consistency, MoE."""
+import dataclasses
+import json
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.models.transformer import MoEConfig, TransformerConfig, model as tm
+from repro.models.transformer import attention as attn
 from repro.models.transformer.attention import chunked_attention, dense_attention
 from repro.models.transformer.moe import init_moe_params, moe_ffn
+from repro.serving.engine import _merge_admitted
 
 CFG = TransformerConfig(
     name="tiny", n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
@@ -170,3 +177,140 @@ def test_int8_kv_cache_decode_accuracy():
         logits, cache = tm.decode_step(params, cache, toks[:, t], cfgq)
         errs.append(float(jnp.max(jnp.abs(logits - full[:, t]))))
     assert max(errs) < 0.05, errs
+
+
+# ------------------------------------------------- in-place decode step ---
+def _masked_step(params, cache, token, cfg):
+    """The decode step with the arena update it replaced: each layer's new
+    row written by a select over every row of the layer, the arena passed
+    through the layer loop and stacked again."""
+    b, sc, cur = token.shape[0], cache.k.shape[2], cache.cursor
+    mask = jnp.arange(sc)[None, :] == (cur % sc)[:, None]
+    pos = jnp.where(mask, cur[:, None], cache.pos)
+    x = params["embed"][token][:, None]
+
+    def put(rows, new):  # rows (B, Sc, ...) <- new (B, ...) at each slot
+        return jnp.where(mask.reshape(mask.shape + (1,) * (rows.ndim - 2)),
+                         new[:, None], rows)
+
+    def llama(x, xs):
+        p, kc, vc, ks, vs = xs
+        xn = tm.rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = tm._attn_proj(p, xn, cfg)
+        q = attn.rope(q, cur[:, None], cfg.rope_theta)
+        k = attn.rope(k, cur[:, None], cfg.rope_theta)
+        if cfg.kv_quant:
+            k, ksc = tm._quant_rows(k)
+            v, vsc = tm._quant_rows(v)
+            ks, vs = put(ks, ksc[:, 0]), put(vs, vsc[:, 0])
+        kc, vc = put(kc, k[:, 0]), put(vc, v[:, 0])
+        o = attn.decode_attention(q, kc, vc, pos, cur, cfg.sliding_window,
+                                  k_scale=ks, v_scale=vs)
+        x = x + (o.reshape(b, 1, -1) @ p["wo"]).astype(x.dtype)
+        xn = tm.rms_norm(x, p["ln2"], cfg.norm_eps)
+        y = (jax.nn.silu(xn @ p["w1"]) * (xn @ p["w3"])) @ p["w2"]
+        return x + y.astype(x.dtype), (kc, vc, ks, vs)
+
+    def mla(carry, xs, dense):
+        x, routed, top = carry
+        p, kc, pc = xs
+        xn = tm.rms_norm(x, p["ln1"], cfg.norm_eps)
+        q_nope, q_pe, c, k_pe = tm._mla_proj(p, xn, cur[:, None], cfg)
+        kc, pc = put(kc, c[:, 0]), put(pc, k_pe[:, 0])
+        o = tm._mla_absorbed(p, q_nope[:, 0], q_pe[:, 0], kc, pc, pos, cur,
+                             cfg)
+        x = x + (o[:, None] @ p["wo"]).astype(x.dtype)
+        xn = tm.rms_norm(x, p["ln2"], cfg.norm_eps)
+        y, _, load = tm._mla_ffn(p, xn.reshape(b, -1), cfg, dense)
+        if load is not None:
+            routed, top = routed + load, jnp.maximum(top, jnp.max(load))
+        return (x + y[:, None].astype(x.dtype), routed, top), (kc, pc)
+
+    counters = {}
+    if cfg.mla is None:
+        x, (kc, vc, ks, vs) = jax.lax.scan(
+            llama, x, (params["layers"], cache.k, cache.v, cache.k_scale,
+                       cache.v_scale))
+    else:
+        ks = vs = None
+        carry = (x, cache.routed, jnp.zeros((), jnp.int32))
+        kcs, pcs, first = [], [], 0
+        for stack, dense in tm._mla_segments(params, cfg):
+            n = jax.tree.leaves(stack)[0].shape[0]
+            carry, (kc, pc) = jax.lax.scan(
+                lambda c, xs, dense=dense: mla(c, xs, dense), carry,
+                (stack, cache.k[first:first + n], cache.v[first:first + n]))
+            kcs.append(kc)
+            pcs.append(pc)
+            first += n
+        x, routed, top = carry
+        kc, vc = jnp.concatenate(kcs), jnp.concatenate(pcs)
+        counters = {"routed": routed,
+                    "max_load": jnp.maximum(cache.max_load, top)}
+    x = tm.rms_norm(x, params["ln_f"], cfg.norm_eps)
+    logits = x[:, 0].astype(jnp.float32) @ params["head"].astype(jnp.float32)
+    return jnp.argmax(logits, -1).astype(jnp.int32), tm.KVCache(
+        k=kc, v=vc, pos=pos, cursor=cur + 1, k_scale=ks, v_scale=vs,
+        **counters)
+
+
+def _step_case(kind: str):
+    """(cfg, params) at bf16: the Llama family here, or the tiny
+    DeepSeek-V2 block of the benchmark's fixtures (latent cache, a leading
+    dense layer, held-share MoE with its routing counters)."""
+    if kind != "mla":
+        cfg = dataclasses.replace(CFG, dtype="bfloat16",
+                                  kv_quant=kind == "kv_quant")
+        return cfg, tm.init_params(jax.random.PRNGKey(0), cfg)
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench import models
+
+    model = json.loads((root / "tests" / "bench" / "fixtures" /
+                        "tiny-DeepseekV2ForCausalLM.json").read_text())["model"]
+    model = dict(model, torch_dtype="bfloat16")
+    fam = models.family(model)
+    return fam.program_config(model, "tiny"), fam.make_params(model, 0)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "kv_quant", "mla"])
+def test_in_place_decode_step_equals_masked_update(kind):
+    """``serve_step`` writes each slot's new row into the donated arena;
+    over 12 steps it emits the tokens and leaves the arena, positions and
+    cursors bit for bit as the masked update over every row did.  Slots
+    start at cursors 12, 5, 9 and empty; slot 3 is admitted after step 3,
+    and slots 0, 1 and 2 wrap past the 16-row arena."""
+    cfg, params = _step_case(kind)
+    prompts = jnp.asarray(np.random.default_rng(0).integers(
+        1, cfg.vocab, (4, 12)), jnp.int32)
+    ref_step = jax.jit(_masked_step, static_argnames=("cfg",))
+
+    def admit(arena, tok, rows, lens):
+        logits, fresh = tm.prefill(params, prompts, jnp.asarray(lens), cfg, 16)
+        first = jnp.argmax(logits, -1).astype(jnp.int32)
+        return _merge_admitted(arena, fresh, tok, first, jnp.arange(4),
+                               jnp.asarray(rows))
+
+    def run(step):
+        arena, tok = admit(tm.init_cache(cfg, 4, 16), jnp.zeros(4, jnp.int32),
+                           [True, True, True, False], [12, 5, 9, 1])
+        trail = []
+        for t in range(12):
+            if t == 3:
+                arena, tok = admit(arena, tok, [False, False, False, True],
+                                   [1, 1, 1, 7])
+            before = arena
+            tok, arena = step(params, arena, tok, cfg=cfg)
+            # copies: a host view of a CPU buffer would keep it from donation
+            trail.append([np.array(a) for a in jax.tree.leaves((tok, arena))])
+            if step is tm.serve_step:  # the arena went to the step
+                assert before.k.is_deleted() and before.v.is_deleted()
+        return trail
+
+    got, ref = run(tm.serve_step), run(ref_step)
+    for t, (g, r) in enumerate(zip(got, ref)):
+        assert g[0].tolist() == r[0].tolist(), t  # the tokens
+        for a, b in zip(g, r, strict=True):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), t
+    assert got[-1][4].tolist() == [24, 17, 21, 16]  # the cursors
