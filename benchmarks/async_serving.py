@@ -32,7 +32,9 @@ from repro.core import (
 )
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import DelayedRetrieval, RAGRequest, RAGServeEngine
+from repro.serving import (
+    DelayedRetrieval, RAGRequest, RAGServeEngine, ServingConfig,
+)
 
 
 def _build(n_nodes: int, seed: int = 0):
@@ -68,8 +70,8 @@ def _requests(g, emb_np, q_ids, max_new):
 
 def _measure(pipe_like, g, emb_np, q_ids, params, cfg, *, slots, max_new,
              prefetch):
-    eng = RAGServeEngine(pipe_like, params, cfg, slots=slots, cache_len=192,
-                         prefetch=prefetch)
+    eng = RAGServeEngine(pipe_like, params, cfg, config=ServingConfig(
+        slots=slots, cache_len=192, prefetch=prefetch))
     t0 = time.perf_counter()
     for r in _requests(g, emb_np, q_ids, max_new):
         eng.submit(r)

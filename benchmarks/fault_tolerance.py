@@ -36,7 +36,9 @@ from repro.core import (
 )
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import FaultyRetrieval, RAGRequest, RAGServeEngine
+from repro.serving import (
+    FaultyRetrieval, RAGRequest, RAGServeEngine, ServingConfig,
+)
 
 
 def _build(n_nodes: int, seed: int = 0):
@@ -72,11 +74,10 @@ def _requests(g, emb_np, q_ids, max_new):
 
 def _measure(pipe_like, g, emb_np, q_ids, params, cfg, *, slots, max_new,
              timeout_s, retries, degraded):
-    eng = RAGServeEngine(
-        pipe_like, params, cfg, slots=slots, cache_len=192, prefetch=True,
+    eng = RAGServeEngine(pipe_like, params, cfg, config=ServingConfig(
+        slots=slots, cache_len=192, prefetch=True,
         retrieval_timeout_s=timeout_s, max_retries=retries,
-        retry_backoff_s=0.0, degraded_mode=degraded,
-    )
+        retry_backoff_s=0.0, degraded_mode=degraded))
     t0 = time.perf_counter()
     for r in _requests(g, emb_np, q_ids, max_new):
         eng.submit(r)

@@ -45,6 +45,7 @@ from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.serving import (
     FaultyReplica, RAGRequest, RAGServeEngine, ReplicaRouter, RetrievalCache,
+    ServingConfig,
 )
 
 
@@ -84,8 +85,8 @@ def _measure(pipe, g, emb_np, q_ids, params, cfg, *, n_replicas, slots,
              max_steps=20_000) -> dict:
     cache = RetrievalCache(capacity=512)
     engines = [
-        RAGServeEngine(pipe, params, cfg, slots=slots, cache_len=192,
-                       prefetch=True, retrieval_cache=cache)
+        RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=slots, cache_len=192, prefetch=True), retrieval_cache=cache)
         for _ in range(n_replicas)
     ]
     if crash_step is not None:

@@ -39,7 +39,7 @@ from repro.core import (
 )
 from repro.graph import CSRGraph, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import RAGRequest, RAGServeEngine
+from repro.serving import RAGRequest, RAGServeEngine, ServingConfig
 
 
 def _build(n_nodes: int, seed: int = 0, index_kind: str = "brute"):
@@ -86,8 +86,9 @@ def _seeded_batch(store, rng, d_feat):
 
 def _measure(store, pipe, g, q_ids, params, cfg, *, slots, max_new,
              write_mix, seed, compact_every):
-    eng = RAGServeEngine(pipe, params, cfg, slots=slots, cache_len=192,
-                         prefetch=True, compact_every=compact_every)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=slots, cache_len=192, prefetch=True,
+        compact_every=compact_every))
     rng = np.random.default_rng(seed)
     for r in _requests(g, q_ids, max_new):
         eng.submit(r)
@@ -133,8 +134,8 @@ def _staleness_probe(store, pipe, g, params, cfg, *, slots, max_new,
     node is a near-duplicate of that query (wired into its neighborhood),
     and re-ask.  The region invalidation must force a re-retrieval that
     surfaces the new node — ``fresh_frac`` counts probes where it did."""
-    eng = RAGServeEngine(pipe, params, cfg, slots=slots, cache_len=192,
-                         prefetch=True)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=slots, cache_len=192, prefetch=True))
     rng = np.random.default_rng(seed + 1)
     fresh = 0
     for p in range(n_probes):

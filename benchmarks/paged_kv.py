@@ -37,7 +37,9 @@ from repro.core import (
 )
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import DelayedRetrieval, RAGRequest, RAGServeEngine
+from repro.serving import (
+    DelayedRetrieval, RAGRequest, RAGServeEngine, ServingConfig,
+)
 
 CACHE_LEN = 192
 BLOCK = 16
@@ -82,10 +84,10 @@ def _skewed_requests(g, emb_np, q_ids, *, short_new, long_new, long_every):
 
 def _measure(pipe_like, reqs_factory, params, cfg, *, slots, paged,
              admission, prefetch=True):
-    eng = RAGServeEngine(pipe_like, params, cfg, slots=slots,
-                         cache_len=CACHE_LEN, prefetch=prefetch,
-                         admission=admission, paged_kv=paged,
-                         kv_block_size=BLOCK if paged else None)
+    eng = RAGServeEngine(pipe_like, params, cfg, config=ServingConfig(
+        slots=slots, cache_len=CACHE_LEN, prefetch=prefetch,
+        admission=admission, paged_kv=paged,
+        kv_block_size=BLOCK if paged else None))
     t0 = time.perf_counter()
     for r in reqs_factory():
         eng.submit(r)

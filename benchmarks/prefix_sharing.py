@@ -35,7 +35,7 @@ from repro.core import (
 )
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import RAGRequest, RAGServeEngine
+from repro.serving import RAGRequest, RAGServeEngine, ServingConfig
 
 CACHE_LEN = 192
 BLOCK = 16
@@ -66,9 +66,9 @@ def _measure(pipe, params, cfg, g, seed_ids, q_ids, *, slots, share,
     """Two-phase workload: a seeding pass admits each unique query once
     (sharing pins its prefilled blocks), then the repeated storm — where
     share-on admissions alias the pinned blocks and allocate only a tail."""
-    eng = RAGServeEngine(pipe, params, cfg, slots=slots, cache_len=CACHE_LEN,
-                         paged_kv=True, kv_block_size=BLOCK,
-                         prefix_share=share)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=slots, cache_len=CACHE_LEN, paged_kv=True, kv_block_size=BLOCK,
+        prefix_share=share))
     emb_np = np.asarray(pipe.node_emb).astype(np.float32)
 
     def req(u, qi):

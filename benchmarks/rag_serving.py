@@ -32,7 +32,9 @@ from repro.core import (
 )
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import RAGRequest, RAGServeEngine, RetrievalCache
+from repro.serving import (
+    RAGRequest, RAGServeEngine, RetrievalCache, ServingConfig,
+)
 
 
 def _build(n_nodes: int, seed: int = 0, index_kind: str = "brute",
@@ -78,7 +80,8 @@ def run(n_nodes: int = 2000, n_requests: int = 32, slots: int = 8,
 
     def make_engine(n_slots, capacity):
         return RAGServeEngine(
-            pipe, params, cfg, slots=n_slots, cache_len=192,
+            pipe, params, cfg,
+            config=ServingConfig(slots=n_slots, cache_len=192),
             retrieval_cache=RetrievalCache(capacity=capacity),
         )
 

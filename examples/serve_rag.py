@@ -20,7 +20,7 @@ from repro.core import (
 )
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.graph import csr_to_ell, generators
-from repro.serving import RAGRequest, RAGServeEngine
+from repro.serving import RAGRequest, RAGServeEngine, ServingConfig
 
 
 def main():
@@ -49,7 +49,8 @@ def main():
         d_head=16, d_ff=256, vocab=vocab.size, dtype="float32",
     )
     params = tm.init_params(jax.random.PRNGKey(0), cfg)
-    eng = RAGServeEngine(pipe, params, cfg, slots=args.slots, cache_len=224)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=args.slots, cache_len=224))
 
     rng = np.random.default_rng(0)
     q_ids = rng.choice(1000, size=args.requests, replace=False)

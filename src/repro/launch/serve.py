@@ -135,14 +135,42 @@ def unserved(done: list) -> list:
     return [r for r in done if r.failed or r.degraded or r.stale]
 
 
+def serving_config(args, cache_len: int) -> ServingConfig:
+    """Every serving flag in one resolved ``ServingConfig``: an unset flag
+    (None) falls through to its ``RGL_*`` variable, then the default."""
+    return ServingConfig.resolve(
+        None,
+        slots=args.slots, cache_len=cache_len,
+        cache_policy=args.cache_policy,
+        cache_ttl=args.cache_ttl,
+        prefetch=args.prefetch,
+        prefetch_depth=args.prefetch_depth,
+        admission=args.admission,
+        spec_decode=args.spec_decode,
+        draft_window=args.draft_window,
+        paged_kv=args.paged_kv,
+        kv_block_size=args.kv_block,
+        kv_pool_blocks=args.pool_blocks,
+        prefix_share=args.prefix_share,
+        retrieval_timeout_s=args.retrieval_timeout,
+        max_retries=args.retries,
+        retry_backoff_s=args.retry_backoff,
+        degraded_mode=args.degraded,
+        max_pending=args.max_pending,
+        shed_policy=args.shed_policy,
+        default_deadline_s=args.deadline,
+        compact_every=args.compact_every,
+    )
+
+
 def _serve_tokens(args) -> None:
     cfg, params = build_lm(args.arch, published=args.published)
-    cache_len = cfg.sliding_window or 128
-    eng = ServeEngine(params, cfg, slots=args.slots, cache_len=cache_len,
-                      spec_decode=args.spec_decode,
-                      draft_window=args.draft_window,
-                      paged_kv=args.paged_kv, block_size=args.kv_block,
-                      pool_blocks=args.pool_blocks)
+    sc = serving_config(args, cfg.sliding_window or 128)
+    eng = ServeEngine(params, cfg, slots=sc.slots, cache_len=sc.cache_len,
+                      spec_decode=sc.spec_decode,
+                      draft_window=sc.draft_window,
+                      paged_kv=sc.paged_kv, kv_block_size=sc.kv_block_size,
+                      kv_pool_blocks=sc.kv_pool_blocks)
     rng = np.random.default_rng(0)
     t0 = time.time()
     for u in range(args.requests):
@@ -189,36 +217,10 @@ def _serve_rag(args) -> list:
         # raise / stall / corrupt, exercising the retry + degradation path
         pipe = FaultyRetrieval(pipe, seed=args.fault_seed,
                                fault_rate=args.fault_rate)
-    cache_len = arena_len(cfg, tok, args.max_new)
-    # one ServingConfig carries every CLI knob (CLI flag > RGL_* env >
-    # default — the same precedence rule as the engine's kwargs)
-    serve_cfg = ServingConfig.resolve(
-        None,
-        slots=args.slots, cache_len=cache_len,
-        cache_policy=args.cache_policy,
-        cache_ttl=args.cache_ttl,
-        prefetch=args.prefetch,
-        prefetch_depth=args.prefetch_depth,
-        admission=args.admission,
-        spec_decode=args.spec_decode,
-        draft_window=args.draft_window,
-        paged_kv=args.paged_kv,
-        kv_block_size=args.kv_block,
-        kv_pool_blocks=args.pool_blocks,
-        prefix_share=args.prefix_share,
-        retrieval_timeout_s=args.retrieval_timeout,
-        max_retries=args.retries,
-        retry_backoff_s=args.retry_backoff,
-        degraded_mode=args.degraded,
-        compact_every=args.compact_every,
-    )
+    serve_cfg = serving_config(args, arena_len(cfg, tok, args.max_new))
     if args.replicas > 1:
         return _serve_rag_fleet(pipe, g, emb, params, cfg, serve_cfg, args)
-    eng = RAGServeEngine(pipe, params, cfg,
-                         config=serve_cfg,
-                         max_pending=args.max_pending,
-                         shed_policy=args.shed_policy,
-                         default_deadline_s=args.deadline)
+    eng = RAGServeEngine(pipe, params, cfg, config=serve_cfg)
     rng = np.random.default_rng(0)
     q_ids = rng.choice(args.nodes, size=args.requests, replace=True)
     emb_np = np.asarray(emb)
@@ -306,14 +308,18 @@ def _drain_with_mutations(eng, store, args, max_steps: int = 10_000) -> list:
 
 
 def _serve_rag_fleet(pipe, g, emb, params, cfg, serve_cfg, args) -> list:
-    # shed/deadline knobs move to the router's front door: the router pins
-    # the absolute deadline at submit and sheds on queue overflow, so the
-    # per-replica engines run unbounded underneath it
+    # shed/deadline options move to the router's front door: the router
+    # pins the absolute deadline at submit and sheds on queue overflow, so
+    # the per-replica engines run unbounded underneath it
     cache = RetrievalCache(capacity=256 * args.replicas,
-                           policy=args.cache_policy, ttl=args.cache_ttl)
+                           policy=serve_cfg.cache_policy,
+                           ttl=serve_cfg.cache_ttl)
+    replica_cfg = dataclasses.replace(serve_cfg, max_pending=0,
+                                      shed_policy="reject",
+                                      default_deadline_s=None)
     engines = [
-        RAGServeEngine(pipe, params, cfg, retrieval_cache=cache,
-                       config=serve_cfg)
+        RAGServeEngine(pipe, params, cfg, config=replica_cfg,
+                       retrieval_cache=cache)
         for _ in range(args.replicas)
     ]
     if args.crash_replica is not None:
@@ -321,13 +327,13 @@ def _serve_rag_fleet(pipe, g, emb, params, cfg, serve_cfg, args) -> list:
                                     crash_step=args.crash_replica)
     router = ReplicaRouter(engines,
                            failover=args.failover,
-                           max_pending=args.max_pending or 0,
-                           shed_policy=args.shed_policy or "reject",
+                           max_pending=serve_cfg.max_pending,
+                           shed_policy=serve_cfg.shed_policy,
                            replica_depth=args.router_depth,
                            health_window=args.router_window,
                            trip_threshold=args.router_trip,
                            cooldown_steps=args.router_cooldown,
-                           default_deadline_s=args.deadline)
+                           default_deadline_s=serve_cfg.default_deadline_s)
     rng = np.random.default_rng(0)
     q_ids = rng.choice(args.nodes, size=args.requests, replace=True)
     emb_np = np.asarray(emb)

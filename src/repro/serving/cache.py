@@ -58,6 +58,9 @@ from collections import OrderedDict
 import numpy as np
 
 POLICIES = ("lru", "lfu", "ttl")
+# query-embedding quantization step of the cache key: embeddings within
+# the same multiple of it in every coordinate share an entry
+QUANT_EPS = 1e-3
 
 
 @dataclasses.dataclass
@@ -120,7 +123,6 @@ class RetrievalCache:
     def __init__(
         self,
         capacity: int = 256,
-        quant_eps: float = 1e-3,
         *,
         policy: str = "lru",
         ttl: float | None = None,
@@ -136,7 +138,6 @@ class RetrievalCache:
                 f"{mutation_flush!r}"
             )
         self.capacity = capacity
-        self.quant_eps = quant_eps
         self.policy = policy
         self.ttl = ttl
         self.region_bucket = max(1, int(region_bucket))
@@ -167,7 +168,7 @@ class RetrievalCache:
 
     def key(self, query_emb) -> bytes:
         q = np.asarray(query_emb, np.float32).ravel()
-        return np.round(q / self.quant_eps).astype(np.int32).tobytes()
+        return np.round(q / QUANT_EPS).astype(np.int32).tobytes()
 
     # -- in-flight miss registry ----------------------------------------------
     def mark_inflight(self, key: bytes, entries: dict | None = None) -> None:

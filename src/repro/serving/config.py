@@ -1,28 +1,26 @@
 """One config surface for the serving stack: ``ServingConfig``.
 
-Nine PRs of organic growth left serving configuration spread over ~15
-``RGL_*`` env vars, per-engine kwargs, and ``launch.serve`` CLI flags with
-ad-hoc precedence.  ``ServingConfig`` consolidates all of it into one
-frozen dataclass — decode arena, retrieval cache, admission/prefetch,
-paged-KV/prefix-share, speculative decode, fault tolerance, router, and
-the online-mutation tier — with ONE documented precedence rule:
+Every serving option — decode arena, retrieval cache, admission/prefetch,
+paged-KV/prefix-share, speculative decode, fault tolerance and the
+online-mutation tier — enters the engines as a field of this frozen
+dataclass, under ONE precedence rule:
 
-    explicit kwarg  >  RGL_* environment variable  >  built-in default
+    field value  >  RGL_* environment variable  >  built-in default
 
-Resolution model: a field value of ``None`` means "not specified here".
-:meth:`ServingConfig.resolve` overlays explicit (non-None) kwargs onto a
-base config, then :meth:`ServingConfig.finalize` fills every remaining
-env-backed ``None`` from its ``RGL_*`` variable (or the built-in default)
-and validates.  :meth:`ServingConfig.from_env` is the no-kwargs resolver.
-Fields whose default is *derived from other fields* (``kv_block_size``,
-``kv_pool_blocks``, ``prefetch_depth``, ``draft_window``,
-``replica_depth``) may legitimately stay ``None`` after finalize; the
-consuming layer derives them exactly as before.
+A field value of ``None`` means "not specified here".
+:meth:`ServingConfig.finalize` fills every remaining env-backed ``None``
+from its ``RGL_*`` variable (or the built-in default) and validates; it is
+the only code of the package that reads an ``RGL_*`` option variable.
+:meth:`ServingConfig.resolve` overlays non-None overrides (CLI flags) onto a
+base config, then finalizes; :meth:`ServingConfig.from_env` is the
+no-overrides resolver.  Fields whose default is *derived from other
+fields* (``kv_block_size``, ``kv_pool_blocks``, ``prefetch_depth``) may
+stay ``None`` after finalize; the consuming layer derives them.
 
-``RAGServeEngine(config=...)``, :class:`repro.serving.router.ReplicaRouter`
-and ``repro.launch.serve`` are built on this; the engines' historical
-kwargs keep working as a deprecation shim (they become the explicit-kwarg
-layer of the same resolution).
+``RAGServeEngine(config=...)`` resolves its config once and hands
+``ServeEngine`` concrete values; ``repro.launch.serve`` builds one config
+from its flags.  A directly built ``ServeEngine`` reads no option from the
+environment.
 
 Env var -> field map (see the README table):
 
@@ -32,7 +30,7 @@ field                     env var                  default
 prefetch                  RGL_PREFETCH             False
 admission                 RGL_ADMISSION            "wave"
 spec_decode               RGL_SPEC_DECODE          False
-draft_window              RGL_DRAFT_WINDOW         4 (engine-derived)
+draft_window              RGL_DRAFT_WINDOW         4
 paged_kv                  RGL_PAGED_KV             False
 kv_block_size             RGL_KV_BLOCK             auto (engine-derived)
 prefix_share              RGL_PREFIX_SHARE         False
@@ -114,13 +112,13 @@ def _admission_default() -> str:
 
 @dataclasses.dataclass(frozen=True)
 class ServingConfig:
-    """Every serving knob in one frozen value (see module docstring).
+    """Every serving option in one frozen value (see module docstring).
 
     ``None`` in an env-backed field means "resolve from the environment";
     after :meth:`finalize` those fields are concrete.  ``None`` in a
-    derived field (``draft_window``, ``kv_block_size``, ``kv_pool_blocks``,
-    ``prefetch_depth``, ``replica_depth``) means "let the consuming layer
-    derive it" and may persist.
+    derived field (``kv_block_size``, ``kv_pool_blocks``,
+    ``prefetch_depth``) means "let the consuming layer derive it" and may
+    persist.
     """
 
     # -- decode arena -----------------------------------------------------
@@ -135,11 +133,8 @@ class ServingConfig:
     prefix_share: Optional[bool] = None
     # -- retrieval cache --------------------------------------------------
     cache_capacity: int = 256
-    quant_eps: float = 1e-3
     cache_policy: str = "lru"
     cache_ttl: Optional[float] = None
-    region_bucket: int = 32
-    mutation_flush: str = "region"
     # -- admission / prefetch ---------------------------------------------
     prefetch: Optional[bool] = None
     prefetch_depth: Optional[int] = None
@@ -152,13 +147,6 @@ class ServingConfig:
     max_pending: Optional[int] = None
     shed_policy: Optional[str] = None
     default_deadline_s: Optional[float] = None
-    # -- replica router ---------------------------------------------------
-    replicas: int = 1
-    failover: bool = True
-    replica_depth: Optional[int] = None
-    health_window: int = 8
-    trip_threshold: int = 3
-    cooldown_steps: int = 8
     # -- online mutation --------------------------------------------------
     mutation: Optional[bool] = None
     compact_every: Optional[int] = None
@@ -178,11 +166,11 @@ class ServingConfig:
     @classmethod
     def resolve(cls, config: Optional["ServingConfig"] = None,
                 **overrides) -> "ServingConfig":
-        """Apply the precedence rule: explicit kwarg > env > default.
+        """Overlay ``overrides`` onto ``config`` and finalize.
 
         ``overrides`` entries that are ``None`` count as "not specified"
-        (they fall through to ``config``, then env, then default) —
-        exactly the contract the engines' historical kwargs had.
+        (they fall through to ``config``, then env, then default), so an
+        unset CLI flag keeps the config's value.
         """
         base = config if config is not None else cls()
         explicit = {k: v for k, v in overrides.items() if v is not None}
@@ -218,9 +206,9 @@ class ServingConfig:
                     f"'evict-oldest'"
                 )
             kw["shed_policy"] = shed
-        if self.draft_window is None and os.environ.get("RGL_DRAFT_WINDOW"):
-            kw["draft_window"] = _env_int("RGL_DRAFT_WINDOW", None)
-        if self.kv_block_size is None and os.environ.get("RGL_KV_BLOCK"):
+        if self.draft_window is None:
+            kw["draft_window"] = _env_int("RGL_DRAFT_WINDOW", 4)
+        if self.kv_block_size is None:
             kw["kv_block_size"] = _env_int("RGL_KV_BLOCK", None)
         if self.cache_ttl is None:
             kw["cache_ttl"] = _env_float("RGL_CACHE_TTL")
@@ -241,9 +229,4 @@ class ServingConfig:
             kw["default_deadline_s"] = _env_float("RGL_DEADLINE")
         if self.compact_every is None:
             kw["compact_every"] = _env_int("RGL_COMPACT_EVERY", 0)
-        if self.mutation_flush not in ("region", "all"):
-            raise ValueError(
-                f"mutation_flush must be 'region' or 'all', got "
-                f"{self.mutation_flush!r}"
-            )
         return dataclasses.replace(self, **kw) if kw else self

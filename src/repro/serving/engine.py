@@ -17,7 +17,7 @@ Two decode modes share the arena:
 * **one-token** (default) — each step is one ``tm.serve_step``: one jitted
   dispatch per output token, so tok/s is bounded by per-step dispatch
   overhead.
-* **self-speculative** (``spec_decode=True`` or ``RGL_SPEC_DECODE=1``) —
+* **self-speculative** (``spec_decode=True``) —
   each step drafts a window of ``draft_window`` tokens per slot from the
   request's own prompt+output history (:mod:`repro.serving.drafter`, no
   second model) and verifies all of them in ONE jitted ``tm.verify_step``
@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import time
 from collections import deque
 from typing import Optional
@@ -48,33 +47,14 @@ from repro import tracing
 from repro.models.transformer import model as tm
 from repro.models.transformer.config import TransformerConfig
 from repro.models.transformer.moe import token_chunks
+from repro.serving.config import env_flag
 from repro.serving.drafter import draft_tokens
-
-
-def env_flag(name: str) -> bool:
-    """Truthy env toggle: only explicit affirmative values enable — anything
-    else (including "no"/"disabled"/unset) stays off."""
-    return os.environ.get(name, "").lower() in ("1", "true", "on", "yes")
-
-
-def _draft_window_default() -> int:
-    """``RGL_DRAFT_WINDOW`` env default.  The raw value is returned
-    unclamped — the constructor applies the same ``>= 2`` validation to the
-    env path as to an explicit ``draft_window=`` argument, so an invalid
-    setting fails loudly instead of being silently rewritten."""
-    raw = os.environ.get("RGL_DRAFT_WINDOW", "4")
-    try:
-        return int(raw)
-    except ValueError:
-        raise ValueError(
-            f"RGL_DRAFT_WINDOW={raw!r} is not an integer"
-        ) from None
 
 
 def _auto_block_size(cache_len: int, preferred: int = 16) -> int:
     """Largest block size <= ``preferred`` dividing ``cache_len``, so the
-    RGL_PAGED_KV env toggle works for any arena length without per-caller
-    block-size plumbing."""
+    paged arena works for any arena length without per-caller block-size
+    plumbing."""
     for b in range(min(preferred, cache_len), 0, -1):
         if cache_len % b == 0:
             return b
@@ -98,7 +78,7 @@ class Request:
     # monotonic admission ticket assigned by the submitting front-end; a
     # stable identity that, unlike id(self), is never reused after GC
     ticket: int = -1
-    # prefix sharing (paged arena + RGL_PREFIX_SHARE, set by the RAG layer):
+    # prefix sharing (paged arena + prefix_share, set by the RAG layer):
     # ``shared_prefix`` names a CachedRetrieval whose pinned prefilled KV
     # blocks cover this request's exact prompt — admission re-validates and
     # aliases them instead of running prefill; ``pin_to`` names an entry
@@ -299,20 +279,23 @@ class ServeEngine:
         eng.submit(Request(uid=0, prompt_ids=ids, max_new_tokens=32))
         finished = eng.run_to_completion()
 
-    ``spec_decode=None`` reads the ``RGL_SPEC_DECODE`` env var (default
-    off); ``draft_window`` defaults to ``RGL_DRAFT_WINDOW`` (4).
+    Every option is a concrete value (default: one-token decode on the
+    contiguous arena); the engine reads no option from the environment.
+    ``RGL_*`` variables reach it only through a resolved
+    :class:`~repro.serving.config.ServingConfig` (``RAGServeEngine``,
+    ``repro.launch.serve``).
 
-    ``paged_kv=None`` reads ``RGL_PAGED_KV`` (default off: contiguous
-    arena).  When paged, the KV arena is a shared pool of
-    ``pool_blocks`` blocks of ``block_size`` tokens
+    ``spec_decode=True`` verifies ``draft_window`` fed tokens per step.
+    ``paged_kv=True`` makes the KV arena a shared pool of
+    ``kv_pool_blocks`` blocks of ``kv_block_size`` tokens
     (:class:`repro.models.transformer.model.PagedKVCache`): a slot only
     holds blocks its cursor has actually crossed, and returns them the
     step its request retires, so total KV memory tracks *live tokens*
     instead of ``slots * cache_len``.  Outputs are bitwise identical to
-    the contiguous arena in both decode modes.  ``block_size=None`` picks
-    the largest divisor of ``cache_len`` <= 16 (override via arg or
-    ``RGL_KV_BLOCK``); ``pool_blocks=None`` sizes the pool to full
-    capacity (``slots * cache_len / block_size`` — never truncates).  An
+    the contiguous arena in both decode modes.  ``kv_block_size=None``
+    picks the largest divisor of ``cache_len`` <= 16;
+    ``kv_pool_blocks=None`` sizes the pool to full
+    capacity (``slots * cache_len / kv_block_size`` — never truncates).  An
     undersized pool is the memory-saving mode: admission gates on block
     availability (FIFO — an oversized head-of-line request blocks the
     queue rather than being skipped), and when live slots outgrow the
@@ -324,9 +307,9 @@ class ServeEngine:
     def __init__(
         self, params, cfg: TransformerConfig, *, slots: int = 8,
         cache_len: int = 512, eos_id: Optional[int] = None,
-        spec_decode: Optional[bool] = None, draft_window: Optional[int] = None,
-        paged_kv: Optional[bool] = None, block_size: Optional[int] = None,
-        pool_blocks: Optional[int] = None, prefix_share: Optional[bool] = None,
+        spec_decode: bool = False, draft_window: int = 4,
+        paged_kv: bool = False, kv_block_size: Optional[int] = None,
+        kv_pool_blocks: Optional[int] = None, prefix_share: bool = False,
         now_fn=time.monotonic,
     ):
         self.params = params
@@ -335,10 +318,8 @@ class ServeEngine:
         self.slots = slots
         self.cache_len = cache_len
         self.eos_id = eos_id
-        self.spec_decode = env_flag("RGL_SPEC_DECODE") if spec_decode is None \
-            else bool(spec_decode)
-        self.draft_window = _draft_window_default() if draft_window is None \
-            else int(draft_window)
+        self.spec_decode = bool(spec_decode)
+        self.draft_window = int(draft_window)
         if self.spec_decode and self.draft_window < 2:
             raise ValueError(
                 f"draft_window must be >= 2 (1 committed token + >= 1 draft),"
@@ -347,8 +328,7 @@ class ServeEngine:
         self.queue: deque = deque()
         self.active: list = [None] * slots
         self.live = np.zeros(slots, bool)
-        self.paged_kv = env_flag("RGL_PAGED_KV") if paged_kv is None \
-            else bool(paged_kv)
+        self.paged_kv = bool(paged_kv)
         if cfg.mla is not None and (self.paged_kv or self.spec_decode):
             raise ValueError(
                 f"config {cfg.name!r} uses latent attention (MLA), whose "
@@ -359,28 +339,22 @@ class ServeEngine:
         # prefix sharing is a paged-arena feature: on a contiguous arena the
         # flag is inert (admission behaves exactly as before), so the
         # contiguous cells of the CI matrix double as the fallback parity leg
-        self.prefix_share = (
-            env_flag("RGL_PREFIX_SHARE") if prefix_share is None
-            else bool(prefix_share)
-        ) and self.paged_kv
+        self.prefix_share = bool(prefix_share) and self.paged_kv
         self.truncations = 0  # requests retired by KV exhaustion (both modes)
-        if block_size is None:
-            env_bs = os.environ.get("RGL_KV_BLOCK", "")
-            block_size = int(env_bs) if env_bs else None
         if self.paged_kv:
-            bs = _auto_block_size(cache_len) if block_size is None \
-                else int(block_size)
+            bs = _auto_block_size(cache_len) if kv_block_size is None \
+                else int(kv_block_size)
             if bs < 1 or cache_len % bs != 0:
                 raise ValueError(
-                    f"block_size={bs} must divide cache_len={cache_len}"
+                    f"kv_block_size={bs} must divide cache_len={cache_len}"
                 )
             self.block_size = bs
             self.max_blocks = cache_len // bs
-            self.pool_blocks = slots * self.max_blocks if pool_blocks is None \
-                else int(pool_blocks)
+            self.pool_blocks = slots * self.max_blocks \
+                if kv_pool_blocks is None else int(kv_pool_blocks)
             if self.pool_blocks < self.max_blocks:
                 raise ValueError(
-                    f"pool_blocks={self.pool_blocks} cannot hold even one "
+                    f"kv_pool_blocks={self.pool_blocks} cannot hold even one "
                     f"full-length request ({self.max_blocks} blocks)"
                 )
             self.cache = tm.init_paged_cache(

@@ -16,8 +16,8 @@ inside one engine.  Three amortization mechanisms drive throughput:
   :class:`~repro.serving.cache.RetrievalCache` keyed on quantized query
   embeddings lets repeated / near-duplicate queries skip index + BFS + filter
   entirely.  Hit/miss counters are exposed as ``engine.cache_hits`` /
-  ``engine.cache_misses``; pick the policy via ``cache_policy`` /
-  ``cache_ttl`` engine kwargs.
+  ``engine.cache_misses``; pick the policy via the ``cache_policy`` /
+  ``cache_ttl`` fields of the engine's ``ServingConfig``.
 * **Async admission prefetch** (``prefetch=True``, or ``RGL_PREFETCH=1``) —
   wave *i+1*'s retrieval is *launched* (dispatched, results left as device
   arrays) while wave *i*'s decode steps run, and *collected* (forced,
@@ -110,12 +110,15 @@ class RAGServeEngine:
 
     Usage::
 
-        eng = RAGServeEngine(pipe, params, cfg, slots=8, cache_len=256)
+        eng = RAGServeEngine(pipe, params, cfg,
+                             config=ServingConfig(slots=8, cache_len=256))
         eng.submit(RAGRequest(uid=0, query_emb=emb, query_text="..."))
         finished = eng.run_to_completion()   # .out_tokens per request
 
     ``pipe`` must carry a tokenizer and node_text (stages 4's inputs).
-    ``prefetch=None`` reads the ``RGL_PREFETCH`` env var (default off).
+    Every serving option is a field of ``config``
+    (:class:`~repro.serving.config.ServingConfig`, resolved once here: field
+    value > ``RGL_*`` env > default); ``self.config`` is the resolved value.
 
     **Fault tolerance.**  Retrieval faults are data-plane events, not
     engine crashes: ``step()`` never raises for one.  A failed miss-group
@@ -144,10 +147,7 @@ class RAGServeEngine:
     ``abort()`` fails all outstanding work and reconciles every layer
     (pending queue, in-flight prefetch waves + cache keys, decode slots +
     paged KV blocks, admission tickets); ``drain()`` is run_to_completion
-    that aborts the stragglers instead of raising.  Env knobs:
-    ``RGL_RETRIEVAL_TIMEOUT``, ``RGL_RETRIES``, ``RGL_RETRY_BACKOFF``,
-    ``RGL_DEADLINE``, ``RGL_MAX_PENDING``, ``RGL_SHED_POLICY``,
-    ``RGL_DEGRADED``.
+    that aborts the stragglers instead of raising.
 
     **Replica embedding.**  The engine is designed to run as one replica of
     a fleet behind :class:`repro.serving.router.ReplicaRouter`: pass the
@@ -162,57 +162,16 @@ class RAGServeEngine:
         pipeline: RGLPipeline,
         params,
         cfg: TransformerConfig,
-        *,
         config: Optional[ServingConfig] = None,
-        slots: Optional[int] = None,
-        cache_len: Optional[int] = None,
-        eos_id: Optional[int] = None,
+        *,
         retrieval_cache: Optional[RetrievalCache] = None,
-        cache_capacity: Optional[int] = None,
-        quant_eps: Optional[float] = None,
-        cache_policy: Optional[str] = None,
-        cache_ttl: Optional[float] = None,
-        prefetch: Optional[bool] = None,
-        prefetch_depth: Optional[int] = None,
-        admission: Optional[str] = None,
-        spec_decode: Optional[bool] = None,
-        draft_window: Optional[int] = None,
-        paged_kv: Optional[bool] = None,
-        kv_block_size: Optional[int] = None,
-        kv_pool_blocks: Optional[int] = None,
-        prefix_share: Optional[bool] = None,
-        retrieval_timeout_s: Optional[float] = None,
-        max_retries: Optional[int] = None,
-        retry_backoff_s: Optional[float] = None,
-        degraded_mode: Optional[bool] = None,
-        max_pending: Optional[int] = None,
-        shed_policy: Optional[str] = None,
-        default_deadline_s: Optional[float] = None,
-        compact_every: Optional[int] = None,
         now_fn=time.monotonic,
         sleep_fn=time.sleep,
     ):
         assert pipeline.tokenizer is not None, "pipeline needs a tokenizer"
         assert pipeline.node_text is not None, "pipeline needs node_text"
-        # one resolution pass: explicit kwarg > config= > RGL_* env > default.
-        # The historical kwargs above are the deprecation shim — each one,
-        # when non-None, becomes an explicit override of the config.
-        self.config = resolved = ServingConfig.resolve(
-            config,
-            slots=slots, cache_len=cache_len, eos_id=eos_id,
-            cache_capacity=cache_capacity, quant_eps=quant_eps,
-            cache_policy=cache_policy, cache_ttl=cache_ttl,
-            prefetch=prefetch, prefetch_depth=prefetch_depth,
-            admission=admission, spec_decode=spec_decode,
-            draft_window=draft_window, paged_kv=paged_kv,
-            kv_block_size=kv_block_size, kv_pool_blocks=kv_pool_blocks,
-            prefix_share=prefix_share,
-            retrieval_timeout_s=retrieval_timeout_s, max_retries=max_retries,
-            retry_backoff_s=retry_backoff_s, degraded_mode=degraded_mode,
-            max_pending=max_pending, shed_policy=shed_policy,
-            default_deadline_s=default_deadline_s,
-            compact_every=compact_every,
-        )
+        # the one resolution pass: field value > RGL_* env > default
+        self.config = resolved = (config or ServingConfig()).finalize()
         if pipeline.tokenizer.max_len >= resolved.cache_len:
             raise ValueError(
                 f"tokenizer.max_len={pipeline.tokenizer.max_len} must be < "
@@ -226,17 +185,14 @@ class RAGServeEngine:
             eos_id=resolved.eos_id,
             spec_decode=resolved.spec_decode,
             draft_window=resolved.draft_window,
-            paged_kv=resolved.paged_kv, block_size=resolved.kv_block_size,
-            pool_blocks=resolved.kv_pool_blocks,
+            paged_kv=resolved.paged_kv, kv_block_size=resolved.kv_block_size,
+            kv_pool_blocks=resolved.kv_pool_blocks,
             prefix_share=resolved.prefix_share, now_fn=now_fn,
         )
         self.cache = retrieval_cache if retrieval_cache is not None else \
             RetrievalCache(capacity=resolved.cache_capacity,
-                           quant_eps=resolved.quant_eps,
                            policy=resolved.cache_policy,
-                           ttl=resolved.cache_ttl,
-                           region_bucket=resolved.region_bucket,
-                           mutation_flush=resolved.mutation_flush)
+                           ttl=resolved.cache_ttl)
         if self.engine.prefix_share:
             # wire the engine's pin protocol to this cache: pins only attach
             # to entries still resident (a pin on an evicted entry would leak

@@ -13,6 +13,7 @@ from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.serving import (
     DelayedRetrieval, RAGRequest, RAGServeEngine, Request, ServeEngine,
+    ServingConfig,
 )
 
 N_NODES = 120
@@ -56,8 +57,8 @@ def _stream(g):
 
 
 def _run(g, pipe, cfg, params, **kw):
-    eng = RAGServeEngine(pipe, params, cfg, slots=SLOTS, cache_len=CACHE_LEN,
-                         **kw)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=SLOTS, cache_len=CACHE_LEN, **kw))
     for r in _stream(g):
         eng.submit(r)
     done = {r.uid: r for r in eng.run_to_completion()}
@@ -114,8 +115,8 @@ def test_overlap_oracle_decode_between_launch_and_collect(stack):
     cost = 0.05
     events = []
     delayed = DelayedRetrieval(pipe, cost_s=cost, events=events)
-    eng = RAGServeEngine(delayed, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         prefetch=True)
+    eng = RAGServeEngine(delayed, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True))
     inner_step = eng.engine.step
 
     def step_logged():
@@ -151,8 +152,8 @@ def test_overlap_oracle_decode_between_launch_and_collect(stack):
 
     # sync schedule on the same delayed pipeline: zero overlap, and the full
     # injected latency shows up as blocking retrieval time
-    sync = RAGServeEngine(delayed, params, cfg, slots=2, cache_len=CACHE_LEN,
-                          prefetch=False)
+    sync = RAGServeEngine(delayed, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=False))
     for u in range(4):
         sync.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                                query_text=g.node_text[u], max_new_tokens=8))
@@ -168,8 +169,8 @@ def test_inflight_key_not_redispatched(stack):
     two waves in flight, so the launch of wave 1 sees wave 0's keys)."""
     g, pipe, cfg, params = stack
     delayed = DelayedRetrieval(pipe, cost_s=0.02)
-    eng = RAGServeEngine(delayed, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         prefetch=True, prefetch_depth=2)
+    eng = RAGServeEngine(delayed, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True, prefetch_depth=2))
     qis = [0, 1, 0, 2]  # wave0 = {0, 1}; wave1 = {0 (in flight), 2}
     for u, qi in enumerate(qis):
         eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[qi]),
@@ -199,9 +200,9 @@ def test_deferred_fallback_when_owner_entry_evicted(stack):
     qis = [0, 1, 0, 2]  # wave0 = {A, B}; wave1 = {A (in flight), C}
 
     def run(prefetch):
-        eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                             cache_capacity=1, prefetch=prefetch,
-                             prefetch_depth=2)
+        eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=2, cache_len=CACHE_LEN, cache_capacity=1, prefetch=prefetch,
+            prefetch_depth=2))
         for u, qi in enumerate(qis):
             eng.submit(RAGRequest(uid=u,
                                   query_emb=np.asarray(g.node_feat[qi]),
@@ -235,8 +236,8 @@ def test_stale_inflight_marker_from_shared_cache_redispatches(stack):
     g, pipe, cfg, params = stack
     cache = RetrievalCache(capacity=8)
     cache.mark_inflight(cache.key(np.asarray(g.node_feat[0])))
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         retrieval_cache=cache, prefetch=True)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True), retrieval_cache=cache)
     eng.submit(RAGRequest(uid=0, query_emb=np.asarray(g.node_feat[0]),
                           query_text=g.node_text[0], max_new_tokens=4))
     done = eng.run_to_completion()
@@ -270,8 +271,8 @@ def test_inflight_keys_released_on_retrieval_failure(stack):
             return RetrievalResult(sub=BoomSub(), seeds=BoomArray(),
                                    n_valid=int(q.shape[0]))
 
-    eng = RAGServeEngine(BoomPipe(pipe), params, cfg, slots=2,
-                         cache_len=CACHE_LEN, prefetch=True)
+    eng = RAGServeEngine(BoomPipe(pipe), params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True))
     eng.submit(RAGRequest(uid=0, query_emb=np.asarray(g.node_feat[0]),
                           query_text=g.node_text[0], max_new_tokens=2))
     done = eng.run_to_completion()
@@ -286,9 +287,10 @@ def test_inflight_keys_released_on_retrieval_failure(stack):
         def retrieve_many(self, q, **kw):
             raise RuntimeError("dispatch boom")
 
-    eng2 = RAGServeEngine(BoomDispatch(pipe), params, cfg, slots=2,
-                          cache_len=CACHE_LEN, prefetch=True,
-                          degraded_mode=False)
+    eng2 = RAGServeEngine(
+        BoomDispatch(pipe), params, cfg,
+        config=ServingConfig(slots=2, cache_len=CACHE_LEN, prefetch=True,
+                             degraded_mode=False))
     eng2.submit(RAGRequest(uid=1, query_emb=np.asarray(g.node_feat[1]),
                            query_text=g.node_text[1], max_new_tokens=2))
     done2 = eng2.run_to_completion()
@@ -303,7 +305,8 @@ def test_admission_tickets_survive_request_churn(stack):
     back to the right RAGRequest via its monotonic ticket (id()-keyed
     mapping could silently cross-wire recycled objects)."""
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN))
     n, seen = 30, {}
     for u in range(n):
         qi = u % 5
@@ -324,7 +327,8 @@ def test_admission_tickets_survive_request_churn(stack):
 
 def test_inner_requests_carry_distinct_tickets(stack):
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN))
     for u in range(4):
         eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                               query_text=g.node_text[u], max_new_tokens=6))
@@ -357,8 +361,8 @@ def test_serve_engine_run_to_completion_raises_on_exhaustion():
 def test_rag_engine_run_to_completion_raises_on_exhaustion(stack):
     g, pipe, cfg, params = stack
     for prefetch in (False, True):
-        eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                             prefetch=prefetch)
+        eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=2, cache_len=CACHE_LEN, prefetch=prefetch))
         for u in range(3):
             eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                                   query_text=g.node_text[u],
@@ -375,13 +379,16 @@ def test_prefetch_env_default_and_override(stack, monkeypatch):
     g, pipe, cfg, params = stack
 
     def make(**kw):
-        return RAGServeEngine(pipe, params, cfg, slots=2,
-                              cache_len=CACHE_LEN, **kw)
+        return RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=2, cache_len=CACHE_LEN, **kw))
 
     monkeypatch.delenv("RGL_PREFETCH", raising=False)
+    assert not ServingConfig.from_env().prefetch
     assert not make().prefetch
     monkeypatch.setenv("RGL_PREFETCH", "1")
+    assert ServingConfig.from_env().prefetch
     assert make().prefetch
+    assert not ServingConfig.resolve(None, prefetch=False).prefetch
     assert not make(prefetch=False).prefetch  # explicit beats env
     monkeypatch.setenv("RGL_PREFETCH", "0")
     assert not make().prefetch

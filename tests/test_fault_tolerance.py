@@ -13,7 +13,7 @@ from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.serving import (
     CachedRetrieval, DelayedRetrieval, FaultyRetrieval, RAGRequest,
-    RAGServeEngine, RetrievalCache, RetrievalFault,
+    RAGServeEngine, RetrievalCache, RetrievalFault, ServingConfig,
 )
 
 N_NODES = 120
@@ -91,9 +91,9 @@ def test_transient_fault_recovers_via_retry_bitwise(stack):
     g, pipe, cfg, params = stack
 
     def run(src, retries):
-        eng = RAGServeEngine(src, params, cfg, slots=SLOTS,
-                             cache_len=CACHE_LEN, prefetch=True,
-                             max_retries=retries, retrieval_timeout_s=0.05)
+        eng = RAGServeEngine(src, params, cfg, config=ServingConfig(
+            slots=SLOTS, cache_len=CACHE_LEN, prefetch=True,
+            max_retries=retries, retrieval_timeout_s=0.05))
         for u in range(6):
             eng.submit(_req(g, u, uid=u))
         done = {r.uid: r for r in eng.run_to_completion()}
@@ -127,9 +127,9 @@ def test_permanent_fault_isolated_to_its_own_request(stack):
     assert bad and len(bad) < 6  # mixed wave compositions
 
     def run(src):
-        eng = RAGServeEngine(src, params, cfg, slots=SLOTS,
-                             cache_len=CACHE_LEN, prefetch=True,
-                             max_retries=1, retrieval_timeout_s=0.05)
+        eng = RAGServeEngine(src, params, cfg, config=ServingConfig(
+            slots=SLOTS, cache_len=CACHE_LEN, prefetch=True, max_retries=1,
+            retrieval_timeout_s=0.05))
         for u in range(6):
             eng.submit(_req(g, u, uid=u))
         done = {r.uid: r for r in eng.run_to_completion()}
@@ -157,7 +157,8 @@ def test_ladder_rung_stale_cache_entry(stack):
     g, pipe, cfg, params = stack
     now = [0.0]
     cache = RetrievalCache(capacity=8, ttl=10.0, now_fn=lambda: now[0])
-    ok = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
+    ok = RAGServeEngine(pipe, params, cfg,
+                        config=ServingConfig(slots=2, cache_len=CACHE_LEN),
                         retrieval_cache=cache)
     ok.submit(_req(g, 0, uid=0))
     clean = ok.run_to_completion()[0]
@@ -166,7 +167,8 @@ def test_ladder_rung_stale_cache_entry(stack):
     now[0] = 100.0  # entry is now TTL-expired (but resident)
     boom = FaultyRetrieval(pipe, seed=0, fault_rate=1.0,
                            fault_types=("dispatch",))
-    eng = RAGServeEngine(boom, params, cfg, slots=2, cache_len=CACHE_LEN,
+    eng = RAGServeEngine(boom, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN),
                          retrieval_cache=cache)
     eng.submit(_req(g, 0, uid=1))
     r = eng.run_to_completion()[0]
@@ -183,9 +185,9 @@ def test_ladder_rung_degraded_per_fault_type(stack, ftype):
     decode: the request completes on a query-only prompt."""
     g, pipe, cfg, params = stack
     boom = FaultyRetrieval(pipe, seed=0, fault_rate=1.0, fault_types=(ftype,))
-    eng = RAGServeEngine(boom, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         prefetch=True, max_retries=1,
-                         retrieval_timeout_s=0.05)
+    eng = RAGServeEngine(boom, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True, max_retries=1,
+        retrieval_timeout_s=0.05))
     eng.submit(_req(g, 0, uid=0, max_new=3))
     r = eng.run_to_completion()[0]
     assert r.done and r.degraded and not r.failed
@@ -200,8 +202,8 @@ def test_ladder_rung_failed_when_degraded_disabled(stack):
     g, pipe, cfg, params = stack
     boom = FaultyRetrieval(pipe, seed=0, fault_rate=1.0,
                            fault_types=("corrupt",))
-    eng = RAGServeEngine(boom, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         degraded_mode=False)
+    eng = RAGServeEngine(boom, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, degraded_mode=False))
     eng.submit(_req(g, 0, uid=7))
     r = eng.run_to_completion()[0]
     assert r.failed and not r.done and not r.degraded
@@ -220,7 +222,8 @@ def test_stuck_row_without_timeout_fails_loud_not_hung(stack, monkeypatch):
     monkeypatch.delenv("RGL_RETRIES", raising=False)
     boom = FaultyRetrieval(pipe, seed=0, fault_rate=1.0,
                            fault_types=("stuck",))
-    eng = RAGServeEngine(boom, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(boom, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN))
     eng.submit(_req(g, 0, uid=0))
     r = eng.run_to_completion()[0]
     assert r.done and r.degraded
@@ -232,7 +235,8 @@ def test_stuck_row_without_timeout_fails_loud_not_hung(stack, monkeypatch):
 def test_deadline_expired_requests_shed_never_dispatched(stack):
     g, pipe, cfg, params = stack
     now = [0.0]
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN),
                          now_fn=lambda: now[0])
     eng.submit(_req(g, 0, uid=0, deadline_s=5.0))
     eng.submit(_req(g, 1, uid=1))  # no deadline: must still complete
@@ -248,24 +252,32 @@ def test_deadline_expired_requests_shed_never_dispatched(stack):
 def test_default_deadline_env_and_kwarg(stack, monkeypatch):
     g, pipe, cfg, params = stack
     now = [0.0]
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         default_deadline_s=2.0, now_fn=lambda: now[0])
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, default_deadline_s=2.0),
+        now_fn=lambda: now[0])
     eng.submit(_req(g, 0, uid=0))
     assert eng.pending[0].deadline_at == 2.0
     monkeypatch.setenv("RGL_DEADLINE", "7.5")
-    eng2 = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
+    assert ServingConfig.from_env().default_deadline_s == 7.5
+    assert ServingConfig.resolve(
+        None, default_deadline_s=2.0).default_deadline_s == 2.0
+    eng2 = RAGServeEngine(pipe, params, cfg,
+                          config=ServingConfig(slots=2, cache_len=CACHE_LEN),
                           now_fn=lambda: now[0])
     assert eng2.default_deadline_s == 7.5
     monkeypatch.setenv("RGL_DEADLINE", "junk")
     with pytest.raises(ValueError, match="RGL_DEADLINE"):
-        RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+        ServingConfig.from_env()
+    with pytest.raises(ValueError, match="RGL_DEADLINE"):
+        RAGServeEngine(pipe, params, cfg,
+                       config=ServingConfig(slots=2, cache_len=CACHE_LEN))
 
 
 def test_bounded_pending_queue_shed_policies(stack):
     g, pipe, cfg, params = stack
     # reject: the newcomer is refused
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         max_pending=2, shed_policy="reject")
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, max_pending=2, shed_policy="reject"))
     assert eng.submit(_req(g, 0, uid=0))
     assert eng.submit(_req(g, 1, uid=1))
     assert not eng.submit(_req(g, 2, uid=2))  # queue full -> shed on arrival
@@ -275,21 +287,23 @@ def test_bounded_pending_queue_shed_policies(stack):
     assert eng.stats()["shed"] == 1
 
     # evict-oldest: the oldest pending request makes room
-    eng2 = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                          max_pending=2, shed_policy="evict-oldest")
+    eng2 = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, max_pending=2,
+        shed_policy="evict-oldest"))
     for u in range(3):
         eng2.submit(_req(g, u, uid=u))
     done2 = {r.uid: r for r in eng2.run_to_completion()}
     assert done2[0].shed and "evict-oldest" in done2[0].error
     assert done2[1].done and done2[2].done
     with pytest.raises(ValueError, match="shed_policy"):
-        RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                       shed_policy="drop-newest")
+        RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=2, cache_len=CACHE_LEN, shed_policy="drop-newest"))
 
 
 def test_submit_validation_rejects_poison_requests(stack):
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN))
     good = np.asarray(g.node_feat[0])
     nan = good.copy()
     nan[0] = np.nan
@@ -318,8 +332,9 @@ def test_abort_reconciles_all_layers_and_engine_reusable(stack, paged):
     drops in-flight waves (releasing their cache keys), sheds the queue, and
     leaves the engine able to serve a fresh workload correctly."""
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         prefetch=True, prefetch_depth=2, paged_kv=paged)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True, prefetch_depth=2,
+        paged_kv=paged))
     for u in range(6):
         eng.submit(_req(g, u, uid=u, max_new=8))
     eng.step()  # some admitted + decoding, some in flight, some pending
@@ -334,8 +349,8 @@ def test_abort_reconciles_all_layers_and_engine_reusable(stack, paged):
     for u in range(3):
         eng.submit(_req(g, u, uid=100 + u))
     redo = {r.uid: r for r in eng.run_to_completion()}
-    ref_eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                             paged_kv=paged)
+    ref_eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, paged_kv=paged))
     for u in range(3):
         ref_eng.submit(_req(g, u, uid=100 + u))
     ref = {r.uid: r for r in ref_eng.run_to_completion()}
@@ -348,8 +363,8 @@ def test_recovery_after_run_to_completion_exhaustion(stack):
     """The PR-motivating bug: a run_to_completion RuntimeError used to leave
     the engine unrecoverable.  abort() reconciles; drain() never raises."""
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         prefetch=True)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True))
     for u in range(4):
         eng.submit(_req(g, u, uid=u, max_new=20))
     with pytest.raises(RuntimeError, match="still pending"):
@@ -363,7 +378,8 @@ def test_recovery_after_run_to_completion_exhaustion(stack):
     _assert_clean(eng)
 
     # drain() folds the same recovery into one call
-    eng2 = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng2 = RAGServeEngine(pipe, params, cfg,
+                          config=ServingConfig(slots=2, cache_len=CACHE_LEN))
     for u in range(4):
         eng2.submit(_req(g, u, uid=u, max_new=20))
     out = eng2.drain(max_steps=2)
@@ -377,9 +393,9 @@ def test_mid_flight_fault_then_fresh_workload(stack):
     g, pipe, cfg, params = stack
     faulty = FaultyRetrieval(pipe, seed=5, fault_rate=1.0,
                              fault_types=("force",), fails_per_row=1)
-    eng = RAGServeEngine(faulty, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         prefetch=True,
-                         max_retries=0)  # first fault goes straight to ladder
+    eng = RAGServeEngine(faulty, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, prefetch=True,
+        max_retries=0))  # first fault goes straight to ladder
     eng.submit(_req(g, 0, uid=0))
     first = eng.run_to_completion()[0]
     assert first.done and first.degraded
@@ -406,10 +422,9 @@ def test_abort_mid_launch_continuous_wave(stack):
 
     delayed = DelayedRetrieval(pipe, cost_s=0.0, cost_fn=cost,
                                now_fn=lambda: clock[0], sleep_fn=sleep)
-    eng = RAGServeEngine(delayed, params, cfg, slots=SLOTS,
-                         cache_len=CACHE_LEN, prefetch=True,
-                         admission="continuous",
-                         now_fn=lambda: clock[0], sleep_fn=sleep)
+    eng = RAGServeEngine(delayed, params, cfg, config=ServingConfig(
+        slots=SLOTS, cache_len=CACHE_LEN, prefetch=True,
+        admission="continuous"), now_fn=lambda: clock[0], sleep_fn=sleep)
     for u in range(3):
         eng.submit(_req(g, u, uid=u))
     eng.step()
@@ -470,11 +485,10 @@ def test_chaos_soak_matrix(stack, prefetch, admission, paged):
     q_ids = [u % 7 for u in range(n)]  # repeats: cache hits + dedup + stale
 
     def run(src, **kw):
-        eng = RAGServeEngine(src, params, cfg, slots=SLOTS,
-                             cache_len=CACHE_LEN, prefetch=prefetch,
-                             admission=admission, paged_kv=paged,
-                             max_retries=1, retrieval_timeout_s=0.05,
-                             **kw)
+        eng = RAGServeEngine(src, params, cfg, config=ServingConfig(
+            slots=SLOTS, cache_len=CACHE_LEN, prefetch=prefetch,
+            admission=admission, paged_kv=paged, max_retries=1,
+            retrieval_timeout_s=0.05, **kw))
         for u, qi in enumerate(q_ids):
             eng.submit(_req(g, qi, uid=u, max_new=4))
         done = {r.uid: r for r in eng.drain()}
@@ -528,10 +542,9 @@ def test_chaos_soak_with_mutations(stack, admission):
                               max_nodes=16, filter_budget=8),
     )
     faulty = FaultyRetrieval(pipe, seed=23, fault_rate=0.25)
-    eng = RAGServeEngine(faulty, params, cfg, slots=SLOTS,
-                         cache_len=CACHE_LEN, prefetch=True,
-                         admission=admission, max_retries=1,
-                         retrieval_timeout_s=0.05, compact_every=6)
+    eng = RAGServeEngine(faulty, params, cfg, config=ServingConfig(
+        slots=SLOTS, cache_len=CACHE_LEN, prefetch=True, admission=admission,
+        max_retries=1, retrieval_timeout_s=0.05, compact_every=6))
     n = 14
     for u in range(n):
         eng.submit(_req(g, u % 7, uid=u, max_new=4))

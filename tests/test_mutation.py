@@ -349,7 +349,7 @@ def test_serving_config_validation():
     with pytest.raises(ValueError, match="max_pending"):
         ServingConfig(max_pending=-1).finalize()
     with pytest.raises(ValueError, match="mutation_flush"):
-        ServingConfig(mutation_flush="sometimes").finalize()
+        RetrievalCache(mutation_flush="sometimes")
     with pytest.raises(TypeError, match="unknown"):
         ServingConfig.resolve(None, not_a_field=1)
 
@@ -383,7 +383,8 @@ def _engine(serving_stack, **kw):
     g, tok, cfg, params, pcfg = serving_stack
     store = MutableGraphStore.build(g, index_kind="brute")
     pipe = store.make_pipeline(tokenizer=tok, config=pcfg)
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=96, **kw)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=96, **kw))
     return store, pipe, eng
 
 
@@ -402,7 +403,8 @@ def test_zero_mutation_serving_bitwise_identical(serving_stack):
         node_emb=jnp.asarray(g.node_feat), tokenizer=tok,
         node_text=g.node_text, config=pcfg,
     )
-    ref_eng = RAGServeEngine(frozen_pipe, params, cfg, slots=2, cache_len=96)
+    ref_eng = RAGServeEngine(frozen_pipe, params, cfg,
+                             config=ServingConfig(slots=2, cache_len=96))
     store, _, eng = _engine(serving_stack)
     for u, qi in enumerate([3, 14, 15, 9, 2, 6]):
         ref_eng.submit(_req(g, qi, uid=u))

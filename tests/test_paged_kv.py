@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import Request, ServeEngine
+from repro.serving import Request, ServeEngine, ServingConfig
 from repro.serving.engine import _auto_block_size
 
 CFG = TransformerConfig(
@@ -115,7 +115,7 @@ def test_paged_matches_offline_greedy():
 
     prompt = np.asarray([5, 9, 3, 22, 41], np.int32)
     eng = ServeEngine(PARAMS, CFG, slots=2, cache_len=32, paged_kv=True,
-                      block_size=8)
+                      kv_block_size=8)
     eng.submit(Request(uid=0, prompt_ids=prompt, max_new_tokens=8))
     done = eng.run_to_completion()
     offline = generate_tokens(
@@ -196,7 +196,7 @@ def test_block_reuse_through_engine_churn():
     every batch drains, the free count returns to full, and the high-water
     mark never exceeds the pool (host mirror == device allocator)."""
     eng = ServeEngine(PARAMS, CFG, slots=2, cache_len=32, paged_kv=True,
-                      block_size=8, pool_blocks=8)
+                      kv_block_size=8, kv_pool_blocks=8)
     rng = np.random.default_rng(9)
     for batch in range(3):
         for u in range(4):
@@ -220,7 +220,7 @@ def test_pool_exhaustion_truncates_and_recovers(spec):
     of wedging or corrupting: everything completes, flags and counters
     agree, and the pool is whole again afterwards."""
     eng = ServeEngine(PARAMS, CFG, slots=4, cache_len=32, paged_kv=True,
-                      block_size=16, pool_blocks=5, spec_decode=spec,
+                      kv_block_size=16, kv_pool_blocks=5, spec_decode=spec,
                       draft_window=4)
     rng = np.random.default_rng(1)
     for u in range(8):
@@ -258,23 +258,54 @@ def test_contiguous_arena_exhaustion_sets_truncated():
 
 
 # ----------------------------------------------------------- configuration ----
-def test_env_toggle_and_validation(monkeypatch):
+def test_env_toggle_and_validation(rag_stack, monkeypatch):
+    """RGL_PAGED_KV / RGL_KV_BLOCK resolve in ServingConfig (field beats
+    env); the decode engine a RAGServeEngine builds follows the resolved
+    config and validates the block geometry."""
+    from repro.serving import RAGServeEngine
+
+    _, pipe, cfg, params = rag_stack
+
     def make(**kw):
-        return ServeEngine(PARAMS, CFG, slots=1, cache_len=32, **kw)
+        return RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=1, cache_len=96, **kw)).engine
 
     monkeypatch.delenv("RGL_PAGED_KV", raising=False)
     monkeypatch.delenv("RGL_KV_BLOCK", raising=False)
+    assert not ServingConfig.from_env().paged_kv
     assert not make().paged_kv
     monkeypatch.setenv("RGL_PAGED_KV", "1")
+    assert ServingConfig.from_env().paged_kv
     eng = make()
     assert eng.paged_kv and eng.block_size == 16
+    assert not ServingConfig.resolve(None, paged_kv=False).paged_kv
     assert not make(paged_kv=False).paged_kv  # explicit beats env
     monkeypatch.setenv("RGL_KV_BLOCK", "8")
+    assert ServingConfig.from_env().kv_block_size == 8
     assert make().block_size == 8
     with pytest.raises(ValueError, match="divide"):
-        make(block_size=7)  # 32 % 7 != 0
+        make(kv_block_size=7)  # 96 % 7 != 0
     with pytest.raises(ValueError, match="pool_blocks"):
-        make(block_size=8, pool_blocks=3)  # < one full-length request
+        make(kv_block_size=8, kv_pool_blocks=3)  # < one full-length request
+
+
+def test_env_reaches_engines_only_through_serving_config(rag_stack,
+                                                          monkeypatch):
+    """RGL_* options are read by ServingConfig alone: a directly built
+    ServeEngine keeps its defaults (contiguous, one-token) under
+    RGL_PAGED_KV=1 / RGL_SPEC_DECODE=1, while a RAGServeEngine resolving
+    its ServingConfig in the same environment is paged and speculative."""
+    from repro.serving import RAGServeEngine
+
+    _, pipe, cfg, params = rag_stack
+    monkeypatch.setenv("RGL_PAGED_KV", "1")
+    monkeypatch.setenv("RGL_SPEC_DECODE", "1")
+    direct = ServeEngine(PARAMS, CFG, slots=1, cache_len=32)
+    assert not direct.paged_kv and not direct.spec_decode
+    rag = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=1, cache_len=96))
+    assert rag.config.paged_kv and rag.config.spec_decode
+    assert rag.engine.paged_kv and rag.engine.spec_decode
 
 
 def test_auto_block_size_divides_any_cache_len():
@@ -318,7 +349,8 @@ def _rag_run(rag_stack, **kw):
     from repro.serving import RAGRequest, RAGServeEngine
 
     g, pipe, cfg, params = rag_stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=96, **kw)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=96, **kw))
     q_ids = [0, 1, 2, 0, 3, 1]
     for u, qi in enumerate(q_ids):
         eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[qi]),
@@ -386,9 +418,9 @@ def test_continuous_admission_dodges_slow_retrieval_row(rag_stack):
 
     def run(admission):
         delayed = DelayedRetrieval(pipe, cost_s=0.0, cost_fn=cost_fn)
-        eng = RAGServeEngine(delayed, params, cfg, slots=2, cache_len=96,
-                             prefetch=True, admission=admission,
-                             cache_capacity=0)
+        eng = RAGServeEngine(delayed, params, cfg, config=ServingConfig(
+            slots=2, cache_len=96, prefetch=True, admission=admission,
+            cache_capacity=0))
         for u in range(5):
             eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                                   query_text=g.node_text[u],
@@ -414,9 +446,9 @@ def test_rag_pool_exhaustion_propagates_truncated(rag_stack):
     from repro.serving import RAGRequest, RAGServeEngine
 
     g, pipe, cfg, params = rag_stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=96,
-                         paged_kv=True, kv_block_size=16, kv_pool_blocks=6,
-                         cache_capacity=0)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=96, paged_kv=True, kv_block_size=16,
+        kv_pool_blocks=6, cache_capacity=0))
     for u in range(4):
         eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                               query_text=g.node_text[u],

@@ -16,7 +16,7 @@ from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.serving import (
     CachedRetrieval, FaultyRetrieval, RAGRequest, RAGServeEngine, Request,
-    RetrievalCache, ServeEngine,
+    RetrievalCache, ServeEngine, ServingConfig,
 )
 
 CFG = TransformerConfig(
@@ -188,7 +188,7 @@ def _share_engine(**kw):
     kw.setdefault("slots", 3)
     kw.setdefault("cache_len", 48)
     kw.setdefault("paged_kv", True)
-    kw.setdefault("block_size", 8)
+    kw.setdefault("kv_block_size", 8)
     return ServeEngine(PARAMS, CFG, **kw)
 
 
@@ -311,8 +311,8 @@ def stack():
 
 def _rag_run(stack, share, src=None, n=12, uniq=4, **kw):
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(src or pipe, params, cfg, slots=SLOTS,
-                         cache_len=CACHE_LEN, prefix_share=share, **kw)
+    eng = RAGServeEngine(src or pipe, params, cfg, config=ServingConfig(
+        slots=SLOTS, cache_len=CACHE_LEN, prefix_share=share, **kw))
     q_ids = [u % uniq for u in range(n)]  # repeat-heavy: sharing regime
     for u, qi in enumerate(q_ids):
         eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[qi]),

@@ -14,7 +14,9 @@ from repro.core.tokenization import subgraph_texts
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.models.transformer.generate import generate_tokens
-from repro.serving import RAGRequest, RAGServeEngine, RetrievalCache
+from repro.serving import (
+    RAGRequest, RAGServeEngine, RetrievalCache, ServingConfig,
+)
 
 N_NODES = 200
 MAX_LEN = 96
@@ -128,7 +130,8 @@ def test_fused_engine_matches_unbatched_pipeline(stack):
     """A single fused-engine request is token-identical to the unbatched
     RGLPipeline + greedy-decode reference path."""
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN))
     eng.submit(RAGRequest(uid=0, query_emb=np.asarray(g.node_feat[0]),
                           query_text=g.node_text[0], max_new_tokens=MAX_NEW))
     done = eng.run_to_completion()
@@ -142,7 +145,8 @@ def test_fused_engine_batch_matches_reference(stack):
     """Batched admission (shared prefill + shared retrieval batch) stays
     token-identical per request."""
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=4, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=4, cache_len=CACHE_LEN))
     for qi in range(4):
         eng.submit(RAGRequest(uid=qi, query_emb=np.asarray(g.node_feat[qi]),
                               query_text=g.node_text[qi],
@@ -170,8 +174,8 @@ def test_fused_engine_on_sharded_index_matches_brute_reference(stack):
         node_emb=pipe.node_emb, tokenizer=pipe.tokenizer,
         node_text=g.node_text, config=pcfg,
     )
-    eng = RAGServeEngine(sharded_pipe, params, cfg, slots=4,
-                         cache_len=CACHE_LEN)
+    eng = RAGServeEngine(sharded_pipe, params, cfg,
+                         config=ServingConfig(slots=4, cache_len=CACHE_LEN))
     for qi in range(4):
         eng.submit(RAGRequest(uid=qi, query_emb=np.asarray(g.node_feat[qi]),
                               query_text=g.node_text[qi],
@@ -187,7 +191,8 @@ def test_fused_engine_on_sharded_index_matches_brute_reference(stack):
 # ------------------------------------------------------------------ cache ----
 def test_retrieval_cache_hit_and_counters(stack):
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN)
+    eng = RAGServeEngine(pipe, params, cfg,
+                         config=ServingConfig(slots=2, cache_len=CACHE_LEN))
 
     def ask(uid):
         eng.submit(RAGRequest(uid=uid, query_emb=np.asarray(g.node_feat[5]),
@@ -245,7 +250,8 @@ def test_oversized_prompt_rejected_loudly(stack):
         eng.submit(Request(uid=0, prompt_ids=np.arange(1, 40, dtype=np.int32)))
     # and the fused engine refuses a tokenizer/arena mismatch at construction
     with pytest.raises(ValueError, match="max_len"):
-        RAGServeEngine(pipe, params, cfg, slots=2, cache_len=MAX_LEN)
+        RAGServeEngine(pipe, params, cfg,
+                       config=ServingConfig(slots=2, cache_len=MAX_LEN))
 
 
 def test_cache_disabled():
@@ -371,6 +377,6 @@ def test_cache_policy_validation_and_engine_kwargs(stack):
     with pytest.raises(ValueError, match="policy"):
         RetrievalCache(policy="mru")
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=CACHE_LEN,
-                         cache_policy="lfu", cache_ttl=60.0)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=CACHE_LEN, cache_policy="lfu", cache_ttl=60.0))
     assert eng.cache.policy == "lfu" and eng.cache.ttl == 60.0

@@ -13,7 +13,7 @@ from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
 from repro.serving import (
     DelayedRetrieval, FaultyReplica, FaultyRetrieval, RAGRequest,
-    RAGServeEngine, ReplicaFault, ReplicaRouter, RetrievalCache,
+    RAGServeEngine, ReplicaFault, ReplicaRouter, RetrievalCache, ServingConfig,
 )
 
 N_NODES = 120
@@ -49,12 +49,17 @@ def _req(g, qi, uid=0, max_new=4, **kw):
 
 
 def _engine(pipe, params, cfg, **kw):
+    """A replica: engine arguments (shared cache, clocks) pass through,
+    every other keyword is a ServingConfig field."""
+    engine_kw = {k: kw.pop(k) for k in ("retrieval_cache", "now_fn",
+                                         "sleep_fn") if k in kw}
     kw.setdefault("slots", SLOTS)
     kw.setdefault("cache_len", CACHE_LEN)
     kw.setdefault("max_pending", 0)
     kw.setdefault("max_retries", 1)
     kw.setdefault("retrieval_timeout_s", 1.0)
-    return RAGServeEngine(pipe, params, cfg, **kw)
+    return RAGServeEngine(pipe, params, cfg, config=ServingConfig(**kw),
+                          **engine_kw)
 
 
 def _fleet(pipe, params, cfg, n, cache=None, **kw):

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import Request, ServeEngine
+from repro.serving import Request, ServeEngine, ServingConfig
 from repro.serving.drafter import draft_tokens
 
 CFG = TransformerConfig(
@@ -270,9 +270,9 @@ def test_rag_engine_parity_across_all_schedules(rag_stack):
     g, pipe, cfg, params = rag_stack
 
     def run(spec, prefetch):
-        eng = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=96,
-                             prefetch=prefetch, spec_decode=spec,
-                             draft_window=4)
+        eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+            slots=2, cache_len=96, prefetch=prefetch, spec_decode=spec,
+            draft_window=4))
         q_ids = [0, 1, 2, 0, 3, 1]
         for u, qi in enumerate(q_ids):
             eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[qi]),
@@ -307,9 +307,10 @@ def test_rag_overlap_telemetry_counts_tokens(rag_stack):
     from repro.serving import DelayedRetrieval, RAGRequest, RAGServeEngine
 
     g, pipe, cfg, params = rag_stack
-    eng = RAGServeEngine(DelayedRetrieval(pipe, cost_s=0.02), params, cfg,
-                         slots=2, cache_len=96, prefetch=True,
-                         spec_decode=True, draft_window=4)
+    eng = RAGServeEngine(
+        DelayedRetrieval(pipe, cost_s=0.02), params, cfg,
+        config=ServingConfig(slots=2, cache_len=96, prefetch=True,
+                             spec_decode=True, draft_window=4))
     for u in range(6):
         eng.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                               query_text=g.node_text[u], max_new_tokens=10))
@@ -319,8 +320,8 @@ def test_rag_overlap_telemetry_counts_tokens(rag_stack):
     assert s["prefetch_waves"] >= 1
     assert s["overlap_tokens"] >= s["overlap_steps"] >= 1
     # sync schedule accrues no overlap at all
-    sync = RAGServeEngine(pipe, params, cfg, slots=2, cache_len=96,
-                          prefetch=False, spec_decode=True)
+    sync = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=2, cache_len=96, prefetch=False, spec_decode=True))
     for u in range(4):
         sync.submit(RAGRequest(uid=u, query_emb=np.asarray(g.node_feat[u]),
                                query_text=g.node_text[u], max_new_tokens=6))
@@ -329,38 +330,57 @@ def test_rag_overlap_telemetry_counts_tokens(rag_stack):
 
 
 # ---------------------------------------------------------- configuration ----
-def test_spec_env_default_and_override(monkeypatch):
+def _config_engine(rag_stack, **kw):
+    """The decode engine a RAGServeEngine builds from
+    ``ServingConfig(slots=1, cache_len=96, **kw)`` in the current env."""
+    from repro.serving import RAGServeEngine
+
+    _, pipe, cfg, params = rag_stack
+    return RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=1, cache_len=96, **kw)).engine
+
+
+def test_spec_env_default_and_override(rag_stack, monkeypatch):
     def make(**kw):
-        return ServeEngine(PARAMS, CFG, slots=1, cache_len=32, **kw)
+        return _config_engine(rag_stack, **kw)
 
     monkeypatch.delenv("RGL_SPEC_DECODE", raising=False)
+    monkeypatch.delenv("RGL_DRAFT_WINDOW", raising=False)
+    assert not ServingConfig.from_env().spec_decode
+    assert ServingConfig.from_env().draft_window == 4
     assert not make().spec_decode
     monkeypatch.setenv("RGL_SPEC_DECODE", "1")
+    assert ServingConfig.from_env().spec_decode
     assert make().spec_decode
+    assert not ServingConfig.resolve(None, spec_decode=False).spec_decode
     assert not make(spec_decode=False).spec_decode  # explicit beats env
     monkeypatch.setenv("RGL_SPEC_DECODE", "0")
     assert not make().spec_decode
     assert make(spec_decode=True).spec_decode
     monkeypatch.setenv("RGL_DRAFT_WINDOW", "6")
+    assert ServingConfig.from_env().draft_window == 6
     assert make(spec_decode=True).draft_window == 6
     with pytest.raises(ValueError, match="draft_window"):
         make(spec_decode=True, draft_window=1)
 
 
-def test_draft_window_env_raises_like_constructor(monkeypatch):
+def test_draft_window_env_raises_like_constructor(rag_stack, monkeypatch):
     """RGL_DRAFT_WINDOW=1 must fail exactly like draft_window=1 — it used
     to be silently clamped to 2, so the same invalid input had two
     behaviors depending on which path set it."""
     def make(**kw):
-        return ServeEngine(PARAMS, CFG, slots=1, cache_len=32, **kw)
+        return _config_engine(rag_stack, **kw)
 
     monkeypatch.setenv("RGL_DRAFT_WINDOW", "1")
+    assert ServingConfig.from_env().draft_window == 1
     with pytest.raises(ValueError, match="draft_window"):
         make(spec_decode=True)
     # non-speculative engines never validate the window (parity with the
     # constructor path, where draft_window=1 is fine if spec is off)
     assert make(spec_decode=False).draft_window == 1
     monkeypatch.setenv("RGL_DRAFT_WINDOW", "banana")
+    with pytest.raises(ValueError, match="RGL_DRAFT_WINDOW"):
+        ServingConfig.from_env()
     with pytest.raises(ValueError, match="RGL_DRAFT_WINDOW"):
         make(spec_decode=True)
 

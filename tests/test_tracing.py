@@ -17,7 +17,9 @@ from repro.core import BruteIndex, GraphTokenizer, PipelineConfig, \
     RGLPipeline, Vocab
 from repro.graph import csr_to_ell, generators
 from repro.models.transformer import TransformerConfig, model as tm
-from repro.serving import RAGRequest, RAGServeEngine, Request, ServeEngine
+from repro.serving import (
+    RAGRequest, RAGServeEngine, Request, ServeEngine, ServingConfig,
+)
 
 N_NODES = 96
 MAX_LEN = 48
@@ -87,8 +89,8 @@ def _read_spans(log_dir) -> list:
 
 def _serve_traced(stack, log_dir, prefetch: bool):
     g, pipe, cfg, params = stack
-    eng = RAGServeEngine(pipe, params, cfg, slots=SLOTS, cache_len=CACHE_LEN,
-                         prefetch=prefetch)
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=SLOTS, cache_len=CACHE_LEN, prefetch=prefetch))
     reqs = _requests(g)
     for r in reqs:
         eng.submit(r)
@@ -146,8 +148,8 @@ def test_request_stamps_in_order(stack, tmp_path, prefetch):
 def test_stamps_use_the_engine_clock(stack):
     g, pipe, cfg, params = stack
     clock = {"t": 100.0}
-    eng = RAGServeEngine(pipe, params, cfg, slots=SLOTS, cache_len=CACHE_LEN,
-                         now_fn=lambda: clock["t"])
+    eng = RAGServeEngine(pipe, params, cfg, config=ServingConfig(
+        slots=SLOTS, cache_len=CACHE_LEN), now_fn=lambda: clock["t"])
     (r,) = _requests(g)[:1]
     eng.submit(r)
     clock["t"] = 101.0
