@@ -15,8 +15,10 @@ over the lifetime of the engine.
 Two decode modes share the arena:
 
 * **one-token** (default) — each step is one ``tm.serve_step``: one jitted
-  dispatch per output token, so tok/s is bounded by per-step dispatch
-  overhead.
+  dispatch per output token.  On the contiguous arena, while every slot is
+  live and none is known to retire, the next step is dispatched before this
+  one's tokens are read (one step of lookahead), so the host's work between
+  two steps runs while the chip decodes.
 * **self-speculative** (``spec_decode=True``) —
   each step drafts a window of ``draft_window`` tokens per slot from the
   request's own prompt+output history (:mod:`repro.serving.drafter`, no
@@ -104,6 +106,15 @@ class _SharePlan:
     tail: int  # donor's partial tail block to COW-copy, -1 if none
     length: int  # prompt tokens covered
     first_tok: int  # the donor prefill's recorded argmax
+
+
+@dataclasses.dataclass
+class _Dispatched:
+    """A one-token step on the chip whose tokens the host has not read."""
+
+    nxt: jax.Array  # (slots,) the step's tokens
+    served: tuple  # the request each slot decoded for (None if dead)
+    cursor: np.ndarray  # every slot's cursor after the step
 
 
 def _bucket_len(n: int, cache_len: int, floor: int = 8) -> int:
@@ -427,6 +438,9 @@ class ServeEngine:
         self.decode_tokens = 0  # tokens committed by decode dispatches
         self.draft_proposed = 0  # draft tokens fed to verification
         self.draft_accepted = 0  # drafts accepted (excludes the free token)
+        # one-token lookahead: the step already on the chip, read next call
+        self._ahead: Optional[_Dispatched] = None
+        self.ahead_steps = 0  # steps read that were dispatched ahead
 
     @property
     def free_slots(self) -> int:
@@ -695,6 +709,7 @@ class ServeEngine:
         afterwards — a fresh workload admits into a clean arena.  Returns
         the aborted requests."""
         out = []
+        self._ahead = None  # its tokens belong to the requests aborted here
         live_idx = [i for i in range(self.slots) if self.live[i]]
         for i in live_idx:
             req = self.active[i]
@@ -957,15 +972,37 @@ class ServeEngine:
             finished.extend(self._retire_pool_exhausted())
         if not self.live.any():
             return finished
-        with tracing.span("decode", live=int(np.count_nonzero(self.live))):
+        ahead = self._may_look_ahead()
+        with tracing.span("decode", live=int(np.count_nonzero(self.live)),
+                          ahead=int(ahead)):
             if self.spec_decode:
                 finished.extend(self._step_spec())
             else:
-                finished.extend(self._step_one())
+                finished.extend(self._step_one(ahead))
         return finished
 
-    def _step_one(self) -> list:
-        """One-token decode: one jitted dispatch emits one token per slot."""
+    def _may_look_ahead(self) -> bool:
+        """Whether the step after the one this call reads may be dispatched
+        before that read.  Only on the contiguous one-token path (the paged
+        dispatch needs the host allocator's view of this step's retirements,
+        a speculative one its accepted counts), and only while every slot is
+        live and no request this step serves is known to retire by it (its
+        budget or a full arena): nothing could be admitted at the next
+        boundary, so admission sees the state a synchronous step leaves.  An
+        EOS cannot be known; its slot's lookahead token is dropped."""
+        if self.paged_kv or self.spec_decode or not self.live.all():
+            return False
+        cur = self._ahead
+        served = self.active if cur is None else cur.served
+        cursor = self._cursor + 1 if cur is None else cur.cursor
+        for i, req in enumerate(self.active):
+            if served[i] is req and (
+                    len(req.out_tokens) + 1 >= req.max_new_tokens
+                    or cursor[i] >= self.cache_len):
+                return False
+        return True
+
+    def _dispatch_one(self) -> _Dispatched:
         if self.paged_kv:
             self._apply_paged_alloc()
             nxt, self.cache = tm.paged_serve_step(
@@ -977,24 +1014,44 @@ class ServeEngine:
                 self.params, self.cache, self.cur_tok, self.cfg
             )
         self.cur_tok = nxt
-        self.decode_steps += 1
         self._cursor += 1  # decode_step advances every slot's cursor
+        return _Dispatched(nxt, tuple(self.active), self._cursor.copy())
+
+    def _step_one(self, ahead: bool) -> list:
+        """One-token decode: one jitted dispatch emits one token per slot.
+        Reads the step dispatched ahead by the previous call, if any, else
+        dispatches one; with ``ahead`` it first queues the next step behind
+        it.  A step still on the chip when this returns is the next call's
+        read, and serves at least one live request: when every request it
+        served has retired it is dropped, so an idle engine has none."""
+        cur = self._ahead
+        if cur is None:
+            cur = self._dispatch_one()
+        else:
+            self.ahead_steps += 1
+        self._ahead = self._dispatch_one() if ahead else None
         finished = []
         live_before = self.live.copy()
         with tracing.span("decode.wait"):
-            toks = np.asarray(nxt)
-        for i, req in enumerate(self.active):
-            if req is None or not self.live[i]:
-                continue
+            toks = np.asarray(cur.nxt)
+        self.decode_steps += 1
+        for i, req in enumerate(cur.served):
+            if req is None or self.active[i] is not req:
+                continue  # retired by an EOS after this step was dispatched
             t = int(toks[i])
             req.out_tokens.append(t)
             self.emitted_tokens += 1
             self.decode_tokens += 1
             self.slot_steps += 1
             self._hist_append(i, [t])
-            self._finish_check(i, req, t, int(self._cursor[i]), finished)
+            self._finish_check(i, req, t, int(cur.cursor[i]), finished)
         if self.paged_kv:
             self._release_retired(live_before)
+        queued = self._ahead
+        if queued is not None and not any(
+                r is not None and r is a
+                for r, a in zip(queued.served, self.active)):
+            self._ahead = None  # every request it served retired: drop it
         return finished
 
     def _step_spec(self) -> list:
@@ -1055,7 +1112,12 @@ class ServeEngine:
         number of tokens a live slot commits per jitted decode dispatch —
         exactly 1.0 in one-token mode, up to ``draft_window`` under
         speculation — i.e. the accepted-tokens/step signal, normalized per
-        slot so batch occupancy does not inflate it."""
+        slot so batch occupancy does not inflate it.  ``decode_steps`` counts
+        the steps whose tokens were read; ``ahead_share`` is the part of them
+        dispatched before the previous step was read.  A step still on the
+        chip belongs to live requests and is counted when ``step`` reads it;
+        an idle engine (every exit: drained, run to completion, aborted)
+        holds none."""
         stats = {
             "spec_decode": self.spec_decode,
             "draft_window": self.draft_window if self.spec_decode else 1,
@@ -1069,6 +1131,8 @@ class ServeEngine:
                 self.draft_accepted / self.draft_proposed
                 if self.draft_proposed else 0.0
             ),
+            "ahead_steps": self.ahead_steps,
+            "ahead_share": self.ahead_steps / max(self.decode_steps, 1),
             "paged_kv": self.paged_kv,
             "truncations": self.truncations,
             "prefix_share": self.prefix_share,

@@ -135,6 +135,25 @@ def test_spans_names_nesting_and_counts(stack, tmp_path, prefetch):
     assert all(1 <= s[3]["live"] <= SLOTS for s in dec)
 
 
+def test_decode_span_says_whether_the_next_step_was_queued(stack, tmp_path):
+    """``ahead=1`` on ``rgl.decode`` when the call dispatched the next step
+    before reading its own: every such step is read by a later call."""
+    _, _, cfg, params = stack
+    eng = ServeEngine(params, cfg, slots=SLOTS, cache_len=CACHE_LEN)
+    for u in range(SLOTS):
+        eng.submit(Request(uid=u, max_new_tokens=6,
+                           prompt_ids=np.arange(3 + u) % cfg.vocab))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run_to_completion()
+    finally:
+        jax.profiler.stop_trace()
+    dec = sorted((s for s in _read_spans(tmp_path) if s[0] == "decode"),
+                 key=lambda s: s[1])
+    assert [s[3]["ahead"] for s in dec] == [1, 1, 1, 1, 0]
+    assert eng.ahead_steps == 4 and eng.decode_steps == len(dec)
+
+
 @pytest.mark.parametrize("prefetch", [False, True], ids=["sync", "prefetch"])
 def test_request_stamps_in_order(stack, tmp_path, prefetch):
     _, reqs, _, _ = _serve_traced(stack, tmp_path, prefetch)
